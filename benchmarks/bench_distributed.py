@@ -22,7 +22,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -46,11 +45,11 @@ def _child(devices: int, n: int, t: int, m: int, k: int) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import timed
+    from benchmarks.common import require_forced_cpu_devices, timed
     from repro.core.distributed import ihtc_sharded, make_data_mesh
     from repro.data import PointStreamConfig, point_chunks, stream_to_mesh
 
-    assert len(jax.devices()) == devices, (len(jax.devices()), devices)
+    require_forced_cpu_devices(devices)
     mesh = make_data_mesh()
     cfg = PointStreamConfig(n=n, d=2, chunk=min(n, 65_536), seed=0,
                             kind="gmm")
@@ -81,7 +80,7 @@ def run(device_counts=(1, 2, 4, 8), n_per_device: int = 8192, *,
         strong_n: int = 0, t: int = 2, m: int = 2, k: int = 3,
         out_path: str = "") -> list:
     """Sweep device counts in subprocesses; returns the per-count rows."""
-    from benchmarks.common import print_csv
+    from benchmarks.common import print_csv, run_child
 
     rows = []
     for p in device_counts:
@@ -89,22 +88,16 @@ def run(device_counts=(1, 2, 4, 8), n_per_device: int = 8192, *,
         env = dict(
             os.environ,
             XLA_FLAGS=f"--xla_force_host_platform_device_count={p}",
-            JAX_PLATFORMS="cpu",
             PYTHONPATH=os.pathsep.join(
                 [os.path.join(_REPO, "src"), _REPO,
                  os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep),
         )
-        proc = subprocess.run(
+        out = run_child(
             [sys.executable, "-m", "benchmarks.bench_distributed", "--_child",
              str(p), "--n", str(n), "--t", str(t), "--m", str(m),
              "--k", str(k)],
-            capture_output=True, text=True, timeout=1800, env=env, cwd=_REPO,
-        )
-        if proc.returncode != 0:
-            print(f"# bench_distributed: devices={p} FAILED\n{proc.stderr}",
-                  file=sys.stderr)
-            continue
-        line = next(l for l in proc.stdout.splitlines()
+            env, _REPO, timeout=1800)
+        line = next(l for l in out.splitlines()
                     if l.startswith("RESULT:"))
         rows.append(json.loads(line[len("RESULT:"):]))
 

@@ -26,7 +26,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -61,11 +60,11 @@ def _child(devices: int, ns, chunk: int, t: int, m: int, d: int,
     import numpy as np
 
     import repro
-    from benchmarks.common import live_mb
+    from benchmarks.common import live_mb, require_forced_cpu_devices
     from repro.core import make_data_mesh
     from repro.data import PointStreamConfig, point_chunks
 
-    assert len(jax.devices()) == devices, (len(jax.devices()), devices)
+    require_forced_cpu_devices(devices)
     mesh = make_data_mesh()
 
     def watched(chunks, peak):
@@ -114,28 +113,23 @@ def run(ns=(4_096, 16_384, 65_536), chunk: int = 2_048, *,
         devices: int = 8, t: int = 2, m: int = 2, d: int = 8, k: int = 4,
         seed: int = 0, mode: str = "quick") -> list:
     """Run the executor matrix in one forced-multi-device subprocess."""
-    from benchmarks.common import print_csv
+    from benchmarks.common import print_csv, run_child
 
     env = dict(
         os.environ,
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-        JAX_PLATFORMS="cpu",
         PYTHONPATH=os.pathsep.join(
             [os.path.join(_REPO, "src"), _REPO,
              os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep),
     )
-    proc = subprocess.run(
+    out = run_child(
         [sys.executable, "-m", "benchmarks.bench_fit_matrix", "--_child",
          str(devices), "--ns", ",".join(str(n) for n in ns),
          "--chunk", str(chunk), "--t", str(t), "--m", str(m),
          "--d", str(d), "--k", str(k), "--seed", str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env, cwd=_REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# bench_fit_matrix FAILED\n{proc.stderr}", file=sys.stderr)
-        return []
+        env, _REPO, timeout=3600)
     rows = [json.loads(line[len("RESULT:"):])
-            for line in proc.stdout.splitlines()
+            for line in out.splitlines()
             if line.startswith("RESULT:")]
 
     print_csv(

@@ -88,6 +88,36 @@ def timed(fn: Callable, *args, warmup: int = 1, iters: int = 1, **kw):
     return out, (time.perf_counter() - t0) / iters
 
 
+def require_forced_cpu_devices(devices: int) -> None:
+    """Guard for the children of the forced-host-device launchers
+    (bench_fit_matrix, bench_distributed): ``--xla_force_host_platform_
+    device_count`` only makes devices on the CPU backend, so on any other
+    backend the child refuses (exit 2) instead of measuring something else.
+    The launchers pass the caller's ``JAX_PLATFORMS`` through unchanged and
+    never force the CPU on a machine that has a chip."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"forced host devices exist only on the CPU backend, but JAX "
+            f"chose {backend!r}; run with JAX_PLATFORMS=cpu to measure the "
+            f"CPU simulation")
+    assert len(jax.devices()) == devices, (len(jax.devices()), devices)
+
+
+def run_child(argv: list, env: dict, cwd: str, timeout: int) -> str:
+    """Run one launcher child; its failure ends the launcher (non-zero)."""
+    import subprocess
+    import sys
+
+    proc = subprocess.run(argv, capture_output=True, text=True,
+                          timeout=timeout, env=env, cwd=cwd)
+    if proc.returncode != 0:
+        print(proc.stderr, file=sys.stderr)
+        raise SystemExit(f"{' '.join(argv[2:4])} failed with exit code "
+                         f"{proc.returncode}")
+    return proc.stdout
+
+
 def print_csv(name: str, rows: list, header: str) -> None:
     """Emit one benchmark table: a ``# name: header`` comment line, then one
     ``name,<row>`` line per row (grep the name to extract the table)."""

@@ -225,6 +225,8 @@ def main() -> None:
     if args.summary_only:
         _bench_json_summary(specs)
         return
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.gate:
         if not args.bench:
             ap.error("--gate needs --bench (which registered harnesses "

@@ -43,6 +43,8 @@ def main():
     ap.add_argument("--t", type=int, default=2)
     ap.add_argument("--m", type=int, default=4)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     mesh = make_data_mesh()

@@ -31,6 +31,8 @@ def main():
     ap.add_argument("--impl", default="auto", choices=("auto", "pallas", "ref"),
                     help="kernel dispatch policy (runtime.configure)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # the paper's §4 mixture: 3 bivariate Gaussians, weights .5/.3/.2
     rng = np.random.default_rng(0)

@@ -31,6 +31,8 @@ def main():
     ap.add_argument("--t", type=int, default=2)
     ap.add_argument("--m", type=int, default=1)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = smoke_config(ARCHS[args.arch])
     bundle = build(cfg)

@@ -36,6 +36,8 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.width:

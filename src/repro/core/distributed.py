@@ -43,13 +43,12 @@ from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import runtime
 from repro.cluster.registry import BackendFn
 from repro.core.itis import ITISResult, level_sizes, validate_reduction_params
-from repro.core.knn import _axis_size, ring_knn
+from repro.core.knn import ring_knn
 from repro.core.plan import (
     FitPlan,
     FitResult,
@@ -69,11 +68,11 @@ def make_data_mesh(n_data: Optional[int] = None):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (the MIS while-loop has no
-    replication rule on jax 0.4.x; correctness is covered by the parity
-    tests instead)."""
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with varying-manual-axes checking off: the per-level
+    programs mix replicated and per-shard values in while-loop carries, and
+    their correctness is pinned by the parity tests instead."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +107,7 @@ def tc_sharded(
     same leftover tie-breaking — with only per-vertex vectors replicated.
     """
     n_local, d = x_local.shape
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     n = n_local * p
     row0 = me * n_local
@@ -247,7 +246,7 @@ def _folded_segment_sum(x_local, ids_local, n_out, weights_local, *,
     concatenated rows (requires P | n_blocks and n_blocks | n, which the
     driver's level padding guarantees).
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     sub = n_blocks // p
     nl = x_local.shape[0]
     pad = (-nl) % sub
@@ -313,7 +312,7 @@ def _itis_level_sharded(x, mass, valid, key, *, t, n_out, weighted, impl,
                         n_blocks, axis_name, mesh, _dispatch=()):
     def level(x_local, mass_local, valid_local, key):
         n_local = x_local.shape[0]
-        p = _axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         me = jax.lax.axis_index(axis_name)
         labels, _, n_clusters = tc_sharded(
             x_local, valid_local, t, key, axis_name=axis_name, impl=impl)

@@ -53,26 +53,6 @@ def resolve_auto_block(n: int, d: int = 0, k: int = 0,
     return AUTO_KNN_BLOCK
 
 
-def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
-    """Mark a replicated value as device-varying along ``axis_name``.
-
-    jax ≥ 0.5 has ``jax.lax.pvary`` for this; on older releases shard_map's
-    replication checker accepts the value as-is, so identity is correct.
-    """
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, (axis_name,)) if fn is not None else x
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of a shard_map mesh axis (works back to jax 0.4.x).
-
-    ``jax.lax.axis_size`` only exists on newer releases; ``psum`` of the
-    literal 1 constant-folds to the same static int everywhere.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(axis_name) if fn is not None else jax.lax.psum(1, axis_name)
-
-
 def knn_graph(
     x: jax.Array,
     k: int,
@@ -146,12 +126,14 @@ def _knn_graph_blocked(
     nq = npad // block
 
     xq = xp.reshape(nq, block, -1)
+    # "auto" takes the fused path on TPU, as ops.knn does for one block
+    fused = ops._resolve(impl, fused=True) in ops._FUSED_IMPLS
 
     def per_query_block(qi):
         q = xq[qi]
         q_gidx = qi * block + jnp.arange(block)
 
-        if impl in ops._FUSED_IMPLS:
+        if fused:
             # fused inner loop: the kernel streams key blocks itself and
             # takes the self-exclusion as a traced global-index array, so
             # the (block, block) distance tile never exists outside VMEM
@@ -197,8 +179,9 @@ def ring_knn(
     """
     n_local = x_local.shape[0]
     if valid is None:
-        valid = _pvary(jnp.ones((n_local,), bool), axis_name)
-    p = _axis_size(axis_name)
+        valid = jax.lax.pcast(jnp.ones((n_local,), bool), axis_name,
+                              to="varying")
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i - 1) % p) for i in range(p)]  # block travels to lower rank
 
@@ -215,8 +198,10 @@ def ring_knn(
         return bd, bi, keys, kval
 
     init = (
-        _pvary(jnp.full((n_local, k), jnp.inf, jnp.float32), axis_name),
-        _pvary(jnp.full((n_local, k), -1, jnp.int32), axis_name),
+        jax.lax.pcast(jnp.full((n_local, k), jnp.inf, jnp.float32),
+                      axis_name, to="varying"),
+        jax.lax.pcast(jnp.full((n_local, k), -1, jnp.int32), axis_name,
+                      to="varying"),
         x_local,
         valid,
     )

@@ -13,8 +13,9 @@ Three entry points, one merge semantics (bit-compatible with the composed
 ``ref.pairwise_sq_l2 + ref.merge_topk`` path — DESIGN.md §16):
 
   * :func:`fused_topk`      — the Pallas kernel (TPU; interpret mode on CPU
-    for the parity tests). Generalizes ``knn_topk`` to query != key sets,
-    takes self-exclusion as a *traced* global-query-index array (so blocked
+    for the parity tests). Serves query != key sets and the self-kNN case
+    (``knn_topk`` is this kernel with ``q_gidx = arange(n)``), takes
+    self-exclusion as a *traced* global-query-index array (so blocked
     drivers can call it under ``lax.map`` with a dynamic block offset), and
     dequantizes int8 key tiles in-register.
   * :func:`fused_topk_xla`  — the same streaming fold expressed as a jnp
@@ -58,7 +59,53 @@ def _lane_pad(d: int) -> int:
     return (-d) % 128 if d > 128 else (128 - d)
 
 
-def _fused_kernel(*refs, k, bq, bk, has_qg, quantized):
+def _tile(block: int, rows: int, align: int) -> int:
+    """Block size for an axis of ``rows`` (already a sublane multiple):
+    the requested ``block`` clamped to the axis, rounded up to ``align``
+    unless one block covers the whole axis. Mosaic requires a block's last
+    dim to be a 128-lane multiple or the full array dim, which is why the
+    key axis (the lane axis of the ``(1, bk)`` validity row and of every
+    distance tile) passes ``align=128`` when compiling; interpret mode
+    passes 1 so the parity tests can still drive multi-block grids at
+    tiny n."""
+    b = min(block, rows)
+    return rows if b >= rows else -(-b // align) * align
+
+
+def _merge_tile(run_d, run_i, d, j, k):
+    """Fold one (bq, bk) distance tile into the running (bq, k) best list.
+
+    k rounds of row-min selection with the tie semantics of
+    ``ref.merge_topk``: the running list (earlier in concat order) wins a
+    tie against the tile, and within either part the lowest column wins,
+    which within the tile is the lowest global key index. The two parts
+    are reduced separately instead of concatenated, and every value stays
+    2-D, because Mosaic lays out neither an unaligned lane concat nor a
+    1-D per-row vector."""
+    bq, bk = d.shape
+    big = jnp.iinfo(jnp.int32).max
+    rcols = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    tcols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    new_d = jnp.full((bq, k), jnp.inf, jnp.float32)
+    new_i = jnp.full((bq, k), -1, jnp.int32)
+    for r in range(k):
+        mr = jnp.min(run_d, axis=1, keepdims=True)
+        mt = jnp.min(d, axis=1, keepdims=True)
+        from_run = mr <= mt
+        cr = jnp.min(jnp.where(run_d == mr, rcols, big), axis=1, keepdims=True)
+        ct = jnp.min(jnp.where(d == mt, tcols, big), axis=1, keepdims=True)
+        ir = jnp.sum(jnp.where(rcols == cr, run_i, 0), axis=1, keepdims=True)
+        md = jnp.where(from_run, mr, mt)
+        mi = jnp.where(from_run, ir, j * bk + ct)
+        new_d = jnp.where(rcols == r, md, new_d)
+        new_i = jnp.where(rcols == r, jnp.where(jnp.isfinite(md), mi, -1),
+                          new_i)
+        run_d = jnp.where(from_run & (rcols == cr), jnp.inf, run_d)
+        d = jnp.where(~from_run & (tcols == ct), jnp.inf, d)
+    return new_d, new_i
+
+
+def _fused_kernel(*refs, k, has_qg, quantized):
     it = iter(refs)
     q_ref = next(it)
     y_ref = next(it)
@@ -74,49 +121,31 @@ def _fused_kernel(*refs, k, bq, bk, has_qg, quantized):
 
     @pl.when(j == 0)
     def _init():
-        bd_ref[...] = jnp.full((bq, k), jnp.inf, jnp.float32)
-        bi_ref[...] = jnp.full((bq, k), -1, jnp.int32)
+        bd_ref[...] = jnp.full(bd_ref.shape, jnp.inf, jnp.float32)
+        bi_ref[...] = jnp.full(bi_ref.shape, -1, jnp.int32)
 
     x = q_ref[...].astype(jnp.float32)  # (bq, d)
     if quantized:
         # dequantize the int8 key tile in-register: padded features carry
         # scale == zero == 0 so they contribute exact 0.0 to the distance
-        y = (y_ref[...].astype(jnp.float32) * scale_ref[...][None, :]
-             + zero_ref[...][None, :])
+        y = y_ref[...].astype(jnp.float32) * scale_ref[...] + zero_ref[...]
     else:
         y = y_ref[...].astype(jnp.float32)  # (bk, d)
-    xn = jnp.sum(x * x, axis=-1)[:, None]
+    xn = jnp.sum(x * x, axis=1, keepdims=True)
     yn = jnp.sum(y * y, axis=-1)[None, :]
     cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     d = jnp.maximum(xn + yn - 2.0 * cross, 0.0)  # (bq, bk)
-
-    kcols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-    d = jnp.where(yv_ref[...][None, :] > 0.0, d, jnp.inf)
+    d = jnp.where(yv_ref[...] > 0.0, d, jnp.inf)  # (1, bk) key validity
     if has_qg:
-        # self-exclusion against *global* key indices; qg is a traced array
-        # so blocked drivers can pass `block_offset + iota` under lax.map
-        d = jnp.where(qg_ref[...][:, None] == kcols, jnp.inf, d)
-
-    # Merge running best (bq, k) with this tile: k rounds of
-    # (row-min, record, mask) — same tie semantics as ref.merge_topk
-    # (earliest index in concat order wins), static unroll, no sorts.
-    cat_d = jnp.concatenate([bd_ref[...], d], axis=1)  # (bq, k+bk)
-    cat_i = jnp.concatenate([bi_ref[...], kcols], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, cat_d.shape, 1)
-    new_d, new_i = [], []
-    for _ in range(k):
-        md = jnp.min(cat_d, axis=1)
-        am = jnp.argmin(cat_d, axis=1)
-        onehot = cols == am[:, None]
-        mi = jnp.sum(jnp.where(onehot, cat_i, 0), axis=1)
-        mi = jnp.where(jnp.isfinite(md), mi, -1)
-        new_d.append(md)
-        new_i.append(mi)
-        cat_d = jnp.where(onehot, jnp.inf, cat_d)
-    bd_ref[...] = jnp.stack(new_d, axis=1)
-    bi_ref[...] = jnp.stack(new_i, axis=1)
+        # self-exclusion against *global* key indices; qg is a traced
+        # (bq, 1) column so blocked drivers can pass `offset + iota`
+        kcols = (jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+                 + j * d.shape[1])
+        d = jnp.where(qg_ref[...] == kcols, jnp.inf, d)
+    bd_ref[...], bi_ref[...] = _merge_tile(bd_ref[...], bi_ref[...], d, j, k)
 
 
 def fused_topk(
@@ -180,24 +209,25 @@ def _fused_topk(
     else:
         key_valid = key_valid.astype(jnp.float32)
 
-    # Tiling (same contract as knn_topk, but query/key axes pad
-    # independently since the sets differ): rows round up to the dtype's
-    # sublane multiple, each axis then pads to its own block multiple so
-    # both grid axes tile with zero remainder.
+    # Tiling: rows round up to the dtype's sublane multiple, each axis
+    # then pads to its own block multiple so both grid axes tile with zero
+    # remainder. Per-key and per-query vectors travel as 2-D blocks, the
+    # validity row as (1, bk) and the self-exclusion column as (bq, 1):
+    # Mosaic refuses the 1-D layouts XLA picks for them.
     qa = _sublane(q.dtype)
     qrows = -(-max(nq, qa) // qa) * qa
-    bq = min(block_q, qrows)
+    bq = _tile(block_q, qrows, qa)
     nqp = -(-qrows // bq) * bq
 
     ka = _sublane(keys.dtype)
     krows = -(-max(p, ka) // ka) * ka
-    bk = min(block_k, krows)
+    bk = _tile(block_k, krows, 1 if interpret else 128)
     pp = -(-krows // bk) * bk
 
     d_pad = _lane_pad(d)
     qp = jnp.pad(q, ((0, nqp - nq), (0, d_pad)))
     yp = jnp.pad(keys, ((0, pp - p), (0, d_pad)))
-    vp = jnp.pad(key_valid, (0, pp - p))
+    vp = jnp.pad(key_valid, (0, pp - p))[None, :]
 
     grid = (nqp // bq, pp // bk)
     dd = qp.shape[1]
@@ -205,23 +235,20 @@ def _fused_topk(
     in_specs = [
         pl.BlockSpec((bq, dd), lambda i, j: (i, 0)),
         pl.BlockSpec((bk, dd), lambda i, j: (j, 0)),
-        pl.BlockSpec((bk,), lambda i, j: (j,)),
+        pl.BlockSpec((1, bk), lambda i, j: (0, j)),
     ]
     if q_gidx is not None:
         # padded query rows get -2: never matches a real key column
         inputs.append(jnp.pad(q_gidx.astype(jnp.int32), (0, nqp - nq),
-                              constant_values=-2))
-        in_specs.append(pl.BlockSpec((bq,), lambda i, j: (i,)))
+                              constant_values=-2)[:, None])
+        in_specs.append(pl.BlockSpec((bq, 1), lambda i, j: (i, 0)))
     if quantized:
-        inputs.append(jnp.pad(keys_scale.astype(jnp.float32), (0, d_pad)))
-        inputs.append(jnp.pad(keys_zero.astype(jnp.float32), (0, d_pad)))
-        in_specs.append(pl.BlockSpec((dd,), lambda i, j: (0,)))
-        in_specs.append(pl.BlockSpec((dd,), lambda i, j: (0,)))
+        for v in (keys_scale, keys_zero):
+            inputs.append(jnp.pad(v.astype(jnp.float32), (0, d_pad))[None, :])
+            in_specs.append(pl.BlockSpec((1, dd), lambda i, j: (0, 0)))
 
     kernel = functools.partial(
-        _fused_kernel, k=k, bq=bq, bk=bk,
-        has_qg=q_gidx is not None, quantized=quantized,
-    )
+        _fused_kernel, k=k, has_qg=q_gidx is not None, quantized=quantized)
     bd, bi = pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,68 +1,22 @@
 """Fused distance + streaming top-k: the TPU-native kNN-graph builder.
 
 This is the kernel that replaces the paper's kd-tree. Instead of
-materializing the (n, m) distance matrix in HBM (the memory wall of
+materializing the (n, n) distance matrix in HBM (the memory wall of
 brute-force kNN), each program computes one (Bq, Bk) distance tile on the
 MXU and folds it into a running (Bq, k) best-list kept in VMEM, so HBM
-traffic is O(n·d + n·k) instead of O(n·m).
+traffic is O(n·d + n·k) instead of O(n²).
 
-Grid: (n/Bq, m/Bk) with the key axis innermost (sequentially revisits the
-same output block — the Pallas TPU accumulation pattern). The merge step is
-a static-k unrolled selection (min + one-hot mask), which avoids dynamic
-gathers and sorts that do not lower to Mosaic.
+The self-kNN graph is the fused nearest/top-k kernel
+(:func:`repro.kernels.fused_assign.fused_topk`) with the points as both
+queries and keys and the self-match excluded through the global query
+index, so there is one kernel body, one tiling rule and one merge.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro import runtime
-
-
-def _knn_kernel(x_ref, y_ref, yv_ref, bd_ref, bi_ref, *, k, bq, bk, exclude_self):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        bd_ref[...] = jnp.full((bq, k), jnp.inf, jnp.float32)
-        bi_ref[...] = jnp.full((bq, k), -1, jnp.int32)
-
-    x = x_ref[...].astype(jnp.float32)  # (bq, d)
-    y = y_ref[...].astype(jnp.float32)  # (bk, d)
-    xn = jnp.sum(x * x, axis=-1)[:, None]
-    yn = jnp.sum(y * y, axis=-1)[None, :]
-    cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    d = jnp.maximum(xn + yn - 2.0 * cross, 0.0)  # (bq, bk)
-
-    kcols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-    d = jnp.where(yv_ref[...][None, :] > 0.0, d, jnp.inf)
-    if exclude_self:
-        i = pl.program_id(0)
-        qrows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-        d = jnp.where(qrows == kcols, jnp.inf, d)
-
-    # Merge running best (bq, k) with this tile (bq, bk): k rounds of
-    # (row-min, record, mask). Static unroll; k is small (t*-1).
-    cat_d = jnp.concatenate([bd_ref[...], d], axis=1)  # (bq, k+bk)
-    cat_i = jnp.concatenate([bi_ref[...], kcols], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, cat_d.shape, 1)
-    new_d, new_i = [], []
-    for _ in range(k):
-        md = jnp.min(cat_d, axis=1)  # (bq,)
-        am = jnp.argmin(cat_d, axis=1)  # (bq,)
-        onehot = cols == am[:, None]
-        mi = jnp.sum(jnp.where(onehot, cat_i, 0), axis=1)
-        mi = jnp.where(jnp.isfinite(md), mi, -1)
-        new_d.append(md)
-        new_i.append(mi)
-        cat_d = jnp.where(onehot, jnp.inf, cat_d)
-    bd_ref[...] = jnp.stack(new_d, axis=1)
-    bi_ref[...] = jnp.stack(new_i, axis=1)
+from repro.kernels.fused_assign import fused_topk
 
 
 def knn_topk(
@@ -79,73 +33,8 @@ def knn_topk(
 
     Returns (dists (n,k) ascending sq-L2, idx (n,k); unfilled slots inf/-1).
     ``block_q``/``block_k`` default to the active runtime config's tile
-    sizes (resolved here, before the jit boundary).
+    sizes.
     """
-    cfg = runtime.active()
-    block_q = cfg.block_q if block_q is None else block_q
-    block_k = cfg.block_k if block_k is None else block_k
-    return _knn_topk(x, k, valid, exclude_self=exclude_self,
-                     block_q=block_q, block_k=block_k, interpret=interpret)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "block_q", "block_k", "exclude_self", "interpret")
-)
-def _knn_topk(
-    x: jax.Array,
-    k: int,
-    valid: jax.Array | None = None,
-    *,
-    exclude_self: bool = True,
-    block_q: int = 256,
-    block_k: int = 512,
-    interpret: bool = False,
-):
-    n, d = x.shape
-    if valid is None:
-        valid = jnp.ones((n,), jnp.float32)
-    else:
-        valid = valid.astype(jnp.float32)
-
-    # Tiling: the grid must cover the padded row count *exactly* in both
-    # axes — Mosaic block shapes that do not divide the array mis-tile the
-    # BlockSpec grid (e.g. n=300, block_q=256, block_k=512 used to pad to
-    # 512 rows with a 300-wide key block: 512 % 300 != 0). The query block
-    # is clamped to an 8-aligned padded row count (so the key-block divisor
-    # search below can never collapse to degenerate sub-sublane widths on a
-    # prime row count), rows are padded to a bq multiple, and the key block
-    # is the largest size <= block_k that divides the padded count: both
-    # grid axes tile with zero remainder.
-    rows8 = -(-max(n, 8) // 8) * 8
-    bq = min(block_q, rows8)
-    np_ = -(-rows8 // bq) * bq  # round padded rows up to a bq multiple
-    limit = min(block_k, np_)
-    bk = next(b for b in range(limit, 0, -1) if np_ % b == 0)
-    pad = np_ - n
-    d_pad = (-d) % 128 if d > 128 else (128 - d)
-    xp = jnp.pad(x, ((0, pad), (0, d_pad)))
-    vp = jnp.pad(valid, (0, pad))
-
-    grid = (np_ // bq, np_ // bk)
-    kernel = functools.partial(
-        _knn_kernel, k=k, bq=bq, bk=bk, exclude_self=exclude_self
-    )
-    bd, bi = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, xp.shape[1]), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, xp.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_, k), jnp.float32),
-            jax.ShapeDtypeStruct((np_, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, xp, vp)
-    return bd[:n], bi[:n]
+    gidx = jnp.arange(x.shape[0], dtype=jnp.int32) if exclude_self else None
+    return fused_topk(x, x, k, valid, q_gidx=gidx, block_q=block_q,
+                      block_k=block_k, interpret=interpret)

@@ -18,18 +18,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_assign import _lane_pad, _tile
+
 
 def _pairwise_kernel(x_ref, y_ref, yv_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)  # (bq, d)
     y = y_ref[...].astype(jnp.float32)  # (bk, d)
-    xn = jnp.sum(x * x, axis=-1)[:, None]  # (bq, 1)
+    xn = jnp.sum(x * x, axis=1, keepdims=True)  # (bq, 1)
     yn = jnp.sum(y * y, axis=-1)[None, :]  # (1, bk)
     cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (bq, bk) — MXU
     d = jnp.maximum(xn + yn - 2.0 * cross, 0.0)
-    valid = yv_ref[...][None, :] > 0.0  # (1, bk)
-    o_ref[...] = jnp.where(valid, d, jnp.inf)
+    o_ref[...] = jnp.where(yv_ref[...] > 0.0, d, jnp.inf)  # (1, bk) validity
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
@@ -50,14 +52,18 @@ def pairwise_sq_l2(
     else:
         y_valid = y_valid.astype(jnp.float32)
 
-    bq = min(block_q, max(n, 8))
-    bk = min(block_k, max(m, 8))
-    n_pad = (-n) % bq
-    m_pad = (-m) % bk
-    d_pad = (-d) % 128 if d > 128 else (128 - d)  # lane-align the contraction
+    # the key axis is the lane axis of the output tile and of the (1, bk)
+    # validity row, so compiled blocks are 128-lane multiples (see _tile)
+    rows = -(-max(n, 8) // 8) * 8
+    cols = -(-max(m, 8) // 8) * 8
+    bq = _tile(block_q, rows, 8)
+    bk = _tile(block_k, cols, 1 if interpret else 128)
+    n_pad = -(-rows // bq) * bq - n
+    m_pad = -(-cols // bk) * bk - m
+    d_pad = _lane_pad(d)
     xp = jnp.pad(x, ((0, n_pad), (0, d_pad)))
     yp = jnp.pad(y, ((0, m_pad), (0, d_pad)))
-    vp = jnp.pad(y_valid, (0, m_pad))  # padded keys invalid -> +inf
+    vp = jnp.pad(y_valid, (0, m_pad))[None, :]  # padded keys invalid -> +inf
 
     grid = (xp.shape[0] // bq, yp.shape[0] // bk)
     out = pl.pallas_call(
@@ -66,7 +72,7 @@ def pairwise_sq_l2(
         in_specs=[
             pl.BlockSpec((bq, xp.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, yp.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], yp.shape[0]), jnp.float32),

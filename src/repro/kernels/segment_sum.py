@@ -6,7 +6,11 @@ segment range on the VPU and contracts it against the (Bn, d) data tile on the
 MXU: ``sums[s] += onehot.T @ (w * x)``. Mass (cluster size) falls out of the
 same contraction against a column of ones.
 
-Grid: (S/Bs, n/Bn), point axis innermost (accumulation pattern).
+Grid: (S/Bs, n/Bn), point axis innermost (accumulation pattern). Ids and
+weights travel as lane-dense (1, Bn) rows and the one-hot tile is built
+segment-major, (Bs, Bn), so its contraction with the (Bn, d) data tile is a
+plain matmul; mass comes out as a (Bs, 1) column. Mosaic refuses the 1-D
+layouts XLA picks for per-row vectors.
 """
 from __future__ import annotations
 
@@ -16,8 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_assign import _tile
 
-def _segsum_kernel(ids_ref, w_ref, x_ref, sums_ref, mass_ref, *, bs, bn):
+
+def _segsum_kernel(ids_ref, w_ref, x_ref, sums_ref, mass_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -25,19 +31,17 @@ def _segsum_kernel(ids_ref, w_ref, x_ref, sums_ref, mass_ref, *, bs, bn):
         sums_ref[...] = jnp.zeros_like(sums_ref)
         mass_ref[...] = jnp.zeros_like(mass_ref)
 
-    ids = ids_ref[...]  # (bn,) global segment ids; out-of-range = dropped
-    w = w_ref[...].astype(jnp.float32)  # (bn,)
-    x = x_ref[...].astype(jnp.float32)  # (bn, d)
-
-    s0 = pl.program_id(0) * bs
-    local = ids - s0  # in [0, bs) iff this block owns the segment
-    seg_cols = jax.lax.broadcasted_iota(jnp.int32, (bn, bs), 1)
-    onehot = (seg_cols == local[:, None]).astype(jnp.float32) * w[:, None]  # (bn, bs)
-
+    bs = sums_ref.shape[0]
+    bn = x_ref.shape[0]
+    # global segment id of each row of the tile; ids outside it drop out
+    seg = jax.lax.broadcasted_iota(jnp.int32, (bs, bn), 0) + pl.program_id(0) * bs
+    onehot = jnp.where(seg == ids_ref[...], w_ref[...], 0.0)  # (bs, bn)
     sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, x_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (bs, d) — MXU
-    mass_ref[...] += jnp.sum(onehot, axis=0)  # (bs,)
+    mass_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)  # (bs, 1)
 
 
 @functools.partial(
@@ -60,32 +64,34 @@ def segment_sum(
     n, d = x.shape
     w = jnp.ones((n,), jnp.float32) if weights is None else weights.astype(jnp.float32)
 
-    bs = min(block_s, max(num_segments, 8))
-    bn = min(block_n, max(n, 8))
-    s_pad = (-num_segments) % bs
-    n_pad = (-n) % bn
+    segs = -(-max(num_segments, 8) // 8) * 8
+    rows = -(-max(n, 8) // 8) * 8
+    bs = _tile(block_s, segs, 8)
+    bn = _tile(block_n, rows, 1 if interpret else 128)  # lane axis of ids/w
+    S = -(-segs // bs) * bs
+    n_pad = -(-rows // bn) * bn - n
     xp = jnp.pad(x, ((0, n_pad), (0, 0)))
-    wp = jnp.pad(w, (0, n_pad))  # zero weight -> no contribution
-    idp = jnp.pad(segment_ids.astype(jnp.int32), (0, n_pad), constant_values=-1)
-    S = num_segments + s_pad
+    wp = jnp.pad(w, (0, n_pad))[None, :]  # zero weight -> no contribution
+    idp = jnp.pad(segment_ids.astype(jnp.int32), (0, n_pad),
+                  constant_values=-1)[None, :]
 
     grid = (S // bs, xp.shape[0] // bn)
     sums, mass = pl.pallas_call(
-        functools.partial(_segsum_kernel, bs=bs, bn=bn),
+        _segsum_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bn,), lambda s, j: (j,)),
-            pl.BlockSpec((bn,), lambda s, j: (j,)),
+            pl.BlockSpec((1, bn), lambda s, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda s, j: (0, j)),
             pl.BlockSpec((bn, d), lambda s, j: (j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bs, d), lambda s, j: (s, 0)),
-            pl.BlockSpec((bs,), lambda s, j: (s,)),
+            pl.BlockSpec((bs, 1), lambda s, j: (s, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((S, d), jnp.float32),
-            jax.ShapeDtypeStruct((S,), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1), jnp.float32),
         ],
         interpret=interpret,
     )(idp, wp, xp)
-    return sums[:num_segments], mass[:num_segments]
+    return sums[:num_segments], mass[:num_segments, 0]

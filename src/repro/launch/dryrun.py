@@ -40,6 +40,9 @@ from repro.utils import hlo as hlo_utils  # noqa: E402
 from repro.utils.roofline import build_report, model_flops_for  # noqa: E402
 from repro.utils.tree import tree_size  # noqa: E402
 
+# the chip the host-device dry-run stands in for (its roofline peaks)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 # long_500k baseline needs sub-quadratic sequence mixing: only ssm/hybrid
 # qualify (DESIGN.md §6). Dense/MoE/enc-dec archs run it only under the
 # --variant ihtc-kv paper-technique compression.
@@ -356,6 +359,7 @@ def run_cell(
         flops=flops_per_chip * chips, hbm_bytes=bytes_per_chip_accessed * chips,
         collective_per_chip_bytes=float(coll.get("total", 0.0)),
         model_flops=mf, bytes_per_chip=peak_bytes,
+        device_kind=TARGET_DEVICE_KIND,
     )
     out = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name, "variant": variant,

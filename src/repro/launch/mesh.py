@@ -10,21 +10,29 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.transformer import ShardingPlan
 
 
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all ``Auto``: the model stack places activations
+    with ``with_sharding_constraint`` and lets the partitioner choose gather
+    and matmul output shardings, which ``jax.make_mesh``'s default of
+    ``Explicit`` axes refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU distribution tests (device count set by the test)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_data_mesh(n_data: Optional[int] = None):

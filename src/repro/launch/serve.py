@@ -27,6 +27,8 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = smoke_config(ARCHS[args.arch]) if args.smoke else ARCHS[args.arch]
     bundle = build(cfg)

@@ -62,6 +62,8 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     shape = SHAPES[args.shape]

@@ -1,11 +1,12 @@
 """Roofline-term computation from compiled dry-run artifacts.
 
-Hardware model (TPU v5e-class, per assignment):
-  197 TFLOP/s bf16 per chip · 819 GB/s HBM per chip · ~50 GB/s/link ICI.
+Hardware model: the per-chip peaks of :data:`PEAKS`, looked up by the
+``device_kind`` JAX reports (a kind missing from the table is an error,
+never a default).
 
-  compute_term   = HLO_FLOPs       / (chips × PEAK_FLOPS)
-  memory_term    = HLO_bytes       / (chips × HBM_BW)
-  collective_term= collective_bytes/ (chips × LINK_BW)
+  compute_term   = HLO_FLOPs       / (chips × flops)
+  memory_term    = HLO_bytes       / (chips × hbm_bw)
+  collective_term= collective_bytes/ link_bw
 
 MODEL_FLOPS = 6·N·D (dense) or 6·N_active·D (MoE), D = tokens processed in
 the step; the MODEL/HLO ratio flags remat- or dispatch-inflated compute.
@@ -15,9 +16,30 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-PEAK_FLOPS = 197e12     # bf16 FLOP/s per chip
-HBM_BW = 819e9          # B/s per chip
-LINK_BW = 50e9          # B/s per ICI link
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float     # bf16 FLOP/s per chip
+    hbm_bw: float    # HBM B/s per chip
+    link_bw: float   # B/s per inter-chip link
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links × 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; an unknown device is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -60,10 +82,12 @@ def build_report(
     collective_per_chip_bytes: float,
     model_flops: float,
     bytes_per_chip: float,
+    device_kind: str,
 ) -> RooflineReport:
-    compute_term = flops / (chips * PEAK_FLOPS)
-    memory_term = hbm_bytes / (chips * HBM_BW)
-    collective_term = collective_per_chip_bytes / LINK_BW
+    pk = peaks(device_kind)
+    compute_term = flops / (chips * pk.flops)
+    memory_term = hbm_bytes / (chips * pk.hbm_bw)
+    collective_term = collective_per_chip_bytes / pk.link_bw
     terms = {
         "compute": compute_term,
         "memory": memory_term,
@@ -71,7 +95,7 @@ def build_report(
     }
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
-    mfu = (model_flops / (chips * PEAK_FLOPS * bound)) if bound > 0 else 0.0
+    mfu = (model_flops / (chips * pk.flops * bound)) if bound > 0 else 0.0
     return RooflineReport(
         arch=arch, shape=shape, mesh=mesh_name, chips=chips,
         hlo_gflops=flops / 1e9, hlo_gbytes=hbm_bytes / 1e9,

@@ -80,7 +80,7 @@ print("TRAIN-STEP-PARITY-OK")
 def test_ring_knn_matches_exact():
     out = _run("""
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.knn import ring_knn, knn_graph
 
 mesh = jax.make_mesh((8,), ("data",))
@@ -103,7 +103,7 @@ print("RING-KNN-OK")
 def test_compressed_psum_error_feedback():
     out = _run("""
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.train.compression import compressed_psum, psum_with_error_feedback
 
 mesh = jax.make_mesh((8,), ("pod",))
@@ -139,7 +139,7 @@ def test_sharded_itis_pipeline():
     size guarantee and the reduction factor on an 8-way mesh."""
     out = _run("""
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import threshold_clustering, itis
 
 mesh = jax.make_mesh((8,), ("data",))
@@ -150,9 +150,9 @@ def shard_tc(x_local):
     r = threshold_clustering(x_local, 2, key=jax.random.PRNGKey(0))
     return r.labels, r.n_clusters.reshape(1)
 
-# check_rep=False: the MIS while-loop has no replication rule on jax 0.4.x
+# check_vma=False: the MIS while-loop carries mix replicated and per-shard values
 labels, ncs = shard_map(shard_tc, mesh=mesh, in_specs=P("data", None),
-                        out_specs=(P("data"), P("data")), check_rep=False)(x)
+                        out_specs=(P("data"), P("data")), check_vma=False)(x)
 labels = np.asarray(labels).reshape(8, 32)
 for s in range(8):
     lab = labels[s]
