@@ -104,7 +104,9 @@ def _kmeans(
         key = jax.random.PRNGKey(0)
     w = jnp.where(valid, weights.astype(jnp.float32), 0.0)
 
-    centers = _plus_plus_init(x, w, valid, k, key, impl)
+    # scope name: a contract with the benchmark (DESIGN.md §20)
+    with jax.named_scope("kmeans.seed"):
+        centers = _plus_plus_init(x, w, valid, k, key, impl)
 
     def assign(centers):
         dist = ops.pairwise_sq_l2(x, centers, impl=impl)  # (n, k)
@@ -134,7 +136,8 @@ def _kmeans(
     labels, dmin = assign(centers)
     inertia = jnp.sum(jnp.where(valid, w * dmin, 0.0))
     labels = jnp.where(valid, labels, -1)
-    return KMeansResult(centers, labels.astype(jnp.int32), inertia, it)
+    return KMeansResult(centers, labels.astype(jnp.int32), inertia,
+                        it.astype(jnp.int32))
 
 
 @register_backend("kmeans")
@@ -148,8 +151,9 @@ def kmeans_masked(
     impl: Optional[str] = None,
     iters: int = 100,
     **_: object,
-) -> jax.Array:
-    """IHTC backend adapter: returns labels only."""
+) -> KMeansResult:
+    """IHTC backend adapter: the whole result, so the planner keeps the
+    Lloyd iteration count beside the labels."""
     return kmeans(
         x, k, valid=valid, weights=weights, key=key, iters=iters, impl=impl
-    ).labels
+    )

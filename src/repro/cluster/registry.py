@@ -13,6 +13,8 @@ Every backend must satisfy the uniform ``BackendFn`` contract::
 
     fn(x, *, valid=None, weights=None, key=None, impl=None, **kwargs)
       -> (n,) int32 labels  (-1 for invalid/noise rows)
+         or a result whose ``.labels`` are those labels; the planner keeps
+         its ``.iters``, where it has one, as the fit's ``lloyd_iters``
 
 ``register_backend`` validates the contract at registration time by
 signature inspection (a backend that silently ignored ``valid`` or

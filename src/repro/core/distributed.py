@@ -142,7 +142,7 @@ def tc_sharded(
         return jnp.maximum(q1, push_max(q1))
 
     priorities = seed_priorities(key, n)  # replicated; identical to 1-device
-    is_seed = luby_mis_rounds(priorities, valid, closed2)
+    is_seed, _ = luby_mis_rounds(priorities, valid, closed2)
 
     # ---- grow: each vertex adjacent to a seed joins that seed ----
     n_arange = jnp.arange(n, dtype=jnp.int32)

@@ -85,6 +85,7 @@ class ITISLevelOut(NamedTuple):
     valid: jax.Array       # (n_out_max,) bool
     assignment: jax.Array  # (n_in,) int32 → [0, n_out_max), -1 for padding
     n_clusters: jax.Array  # () int32
+    mis_rounds: Optional[jax.Array] = None  # () int32 Luby rounds of the level
 
 
 class ITISResult(NamedTuple):
@@ -93,6 +94,7 @@ class ITISResult(NamedTuple):
     valid: jax.Array
     assignments: Sequence[jax.Array]  # one per level, for back-out
     n_prototypes: jax.Array           # () int32 — valid count at final level
+    mis_rounds: Sequence[jax.Array] = ()  # () int32 per level run
 
 
 def itis_step(
@@ -149,11 +151,13 @@ def _itis_step(
     tc: TCResult = threshold_clustering(
         x, t, valid=valid, key=key, impl=impl, knn_block=knn_block
     )
-    ps: PrototypeSet = reduce_to_prototypes(
-        x, tc.labels, n_out, weights=mass, weighted=weighted, impl=impl,
-        n_blocks=n_blocks,
-    )
-    return ITISLevelOut(ps.x, ps.mass, ps.valid, tc.labels, tc.n_clusters)
+    with jax.named_scope("itis.reduce"):
+        ps: PrototypeSet = reduce_to_prototypes(
+            x, tc.labels, n_out, weights=mass, weighted=weighted, impl=impl,
+            n_blocks=n_blocks,
+        )
+    return ITISLevelOut(ps.x, ps.mass, ps.valid, tc.labels, tc.n_clusters,
+                        tc.mis_rounds)
 
 
 def itis(
@@ -198,21 +202,25 @@ def itis(
         mass = jnp.pad(mass, (0, pad))
         valid = jnp.pad(valid, (0, pad))
 
-    assignments = []
+    assignments, mis_rounds = [], []
     cur_x, cur_m, cur_v = x, mass, valid
     n_protos = jnp.sum(cur_v).astype(jnp.int32)
     for level in range(m):
-        # repro: allow[HS202]: deliberate per-level sync — the early-exit floor is a host decision, m times per fit
-        n_valid = int(jnp.sum(cur_v))
+        # host span names are a contract with the benchmark (DESIGN.md §20)
+        with jax.profiler.TraceAnnotation("repro.itis.sync"):
+            # repro: allow[HS202]: deliberate per-level sync — the early-exit floor is a host decision, m times per fit
+            n_valid = int(jnp.sum(cur_v))
         if n_valid < max(min_points, 2 * t):
             break
-        key, sub = jax.random.split(key)
-        out = itis_step(
-            cur_x, cur_m, cur_v, t,
-            key=sub, weighted=weighted, impl=impl, knn_block=knn_block,
-            n_out=sizes[level + 1], n_blocks=n_blocks,
-        )
+        with jax.profiler.TraceAnnotation("repro.itis.level"):
+            key, sub = jax.random.split(key)
+            out = itis_step(
+                cur_x, cur_m, cur_v, t,
+                key=sub, weighted=weighted, impl=impl, knn_block=knn_block,
+                n_out=sizes[level + 1], n_blocks=n_blocks,
+            )
         assignments.append(out.assignment)
+        mis_rounds.append(out.mis_rounds)
         cur_x, cur_m, cur_v = out.protos, out.mass, out.valid
         n_protos = out.n_clusters
-    return ITISResult(cur_x, cur_m, cur_v, assignments, n_protos)
+    return ITISResult(cur_x, cur_m, cur_v, assignments, n_protos, mis_rounds)

@@ -189,6 +189,7 @@ class Reduction(NamedTuple):
     assignments: Sequence[jax.Array]  # device level maps ([] for streaming)
     n0: int                    # original unit count (back-out slice length)
     spill: Optional[LabelSpill] = None
+    counters: Optional[Dict[str, Any]] = None  # see FitResult.counters
 
 
 # ---------------------------------------------------------------------------
@@ -238,6 +239,12 @@ class FitResult:
     labels chunk-by-chunk for either family; ``to_index()`` freezes the
     servable :class:`repro.core.index.ClusterIndex`.
 
+    ``counters``: device int32 counts the fit's programs returned, read
+    without a host sync (DESIGN.md §20): ``mis_rounds`` (memory executor,
+    a tuple of () scalars, the Luby rounds of each level run;
+    ``np.asarray`` stacks them) and ``lloyd_iters`` (k-means backend, a
+    () scalar). Other executors and backends leave theirs out.
+
     The old ``IHTCResult`` / ``StreamingIHTCResult`` names are deprecation
     aliases of this class.
     """
@@ -254,6 +261,7 @@ class FitResult:
         assignments: Sequence[jax.Array] = (),
         labels: Optional[jax.Array] = None,
         spill: Optional[LabelSpill] = None,
+        counters: Optional[Dict[str, Any]] = None,
     ):
         if (labels is None) == (spill is None):
             raise ValueError("FitResult needs exactly one of labels= "
@@ -266,6 +274,7 @@ class FitResult:
         self.n_prototypes = n_prototypes
         self.assignments = assignments
         self.spill = spill
+        self.counters = dict(counters or {})
         self._labels = labels
         self._proto_labels_host: Optional[np.ndarray] = None
 
@@ -628,16 +637,20 @@ def plan_fit(
 # ---------------------------------------------------------------------------
 
 
-def _finalize_backend(plan: FitPlan, red: Reduction) -> jax.Array:
+def _finalize_backend(
+    plan: FitPlan, red: Reduction
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Label the final prototype buffer: registry resolution, mass
     weighting, and ``-1`` masking of invalid rows — identical for every
     executor. Sharded executors keep ``backend="kmeans"`` on the mesh
     (:func:`repro.core.distributed.kmeans_sharded`); any other backend runs
     single-device on the already-reduced prototype set (O(n/(t*)^m) rows —
-    the raw points are still never gathered)."""
+    the raw points are still never gathered). Returns the labels and the
+    backend's counters: ``lloyd_iters`` where its result has ``.iters``."""
     _, key_backend = plan.split_keys()
     w = red.mass if plan.use_mass_in_backend else None
     kwargs = dict(plan.backend_kwargs)
+    counters: Dict[str, jax.Array] = {}
     if plan.executor in SHARDED_EXECUTORS and plan.backend == "kmeans":
         from repro.core.distributed import kmeans_sharded  # lazy: no cycle
 
@@ -658,9 +671,13 @@ def _finalize_backend(plan: FitPlan, red: Reduction) -> jax.Array:
             protos = jax.device_get(protos)  # repro: allow[HS201]: sharded epilogue gather
             pvalid = jax.device_get(pvalid)  # repro: allow[HS201]: sharded epilogue gather
             pw = None if pw is None else jax.device_get(pw)  # repro: allow[HS201]: sharded epilogue gather
-        proto_labels = fn(protos, valid=pvalid, weights=pw, key=key_backend,
-                          impl=plan.impl, **kwargs)
-    return jnp.where(red.valid, proto_labels, -1).astype(jnp.int32)
+        out = fn(protos, valid=pvalid, weights=pw, key=key_backend,
+                 impl=plan.impl, **kwargs)
+        proto_labels = getattr(out, "labels", out)
+        iters = getattr(out, "iters", None)
+        if iters is not None:
+            counters["lloyd_iters"] = iters
+    return jnp.where(red.valid, proto_labels, -1).astype(jnp.int32), counters
 
 
 def _plan_scope(plan: FitPlan):
@@ -683,12 +700,13 @@ def finalize_reduction(plan: FitPlan, red: Reduction) -> FitResult:
     can re-finalize a live reservoir snapshot into a fresh
     :class:`FitResult` through the identical code path."""
     with _plan_scope(plan):
-        proto_labels = _finalize_backend(plan, red)
+        proto_labels, backend_counters = _finalize_backend(plan, red)
+    counters = {**(red.counters or {}), **backend_counters}
     if red.spill is not None:
         return FitResult(
             executor=plan.executor, protos=red.protos, proto_mass=red.mass,
             proto_valid=red.valid, proto_labels=proto_labels,
-            n_prototypes=red.n_prototypes, spill=red.spill)
+            n_prototypes=red.n_prototypes, spill=red.spill, counters=counters)
     if red.assignments:
         labels = compose_assignments(red.assignments, proto_labels)
     else:  # m == 0 or early-stop before level 0: backend ran on x itself
@@ -698,7 +716,7 @@ def finalize_reduction(plan: FitPlan, red: Reduction) -> FitResult:
         executor=plan.executor, protos=red.protos, proto_mass=red.mass,
         proto_valid=red.valid, proto_labels=proto_labels,
         n_prototypes=red.n_prototypes, assignments=red.assignments,
-        labels=labels)
+        labels=labels, counters=counters)
 
 
 def execute_plan(plan: FitPlan, data: Any) -> FitResult:
@@ -717,7 +735,8 @@ def execute_plan(plan: FitPlan, data: Any) -> FitResult:
     """
     with _plan_scope(plan):
         red = resolve_executor(plan.executor)(plan, data)
-    return finalize_reduction(plan, red)
+    with jax.profiler.TraceAnnotation("repro.fit.finalize"):
+        return finalize_reduction(plan, red)
 
 
 def fit(
@@ -741,5 +760,8 @@ def fit(
 
     Returns the canonical :class:`FitResult`.
     """
-    plan = plan_fit(data, t, m, backend, **kwargs)
-    return execute_plan(plan, data)
+    # host span names are a contract with the benchmark (DESIGN.md §20)
+    with jax.profiler.TraceAnnotation("repro.fit"):
+        with jax.profiler.TraceAnnotation("repro.fit.plan"):
+            plan = plan_fit(data, t, m, backend, **kwargs)
+        return execute_plan(plan, data)

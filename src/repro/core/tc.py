@@ -30,7 +30,7 @@ iterations under fixed XLA shapes.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +46,7 @@ class TCResult(NamedTuple):
     seed_of: jax.Array      # (n,) int32 vertex index of the owning seed, -1 invalid
     is_seed: jax.Array      # (n,) bool
     n_clusters: jax.Array   # () int32
+    mis_rounds: Optional[jax.Array] = None  # () int32 Luby rounds run
 
 
 def _push_max(p: jax.Array, idx: jax.Array, idx_ok: jax.Array) -> jax.Array:
@@ -69,8 +70,11 @@ def _closed2_max(p: jax.Array, idx: jax.Array, idx_ok: jax.Array) -> jax.Array:
     return jnp.maximum(q1, _push_max(q1, idx, idx_ok))
 
 
-def luby_mis_rounds(priorities: jax.Array, active0: jax.Array, closed2_max) -> jax.Array:
-    """Maximal independent set via parallel local-maxima rounds.
+def luby_mis_rounds(
+    priorities: jax.Array, active0: jax.Array, closed2_max
+) -> Tuple[jax.Array, jax.Array]:
+    """Maximal independent set via parallel local-maxima rounds; returns
+    ``(is_seed, rounds)``, ``rounds`` the () int32 count of rounds run.
 
     ``closed2_max(p)`` must return, per vertex, the max of ``p`` over that
     vertex's closed ≤2-hop neighbourhood. The single-device path passes
@@ -81,11 +85,11 @@ def luby_mis_rounds(priorities: jax.Array, active0: jax.Array, closed2_max) -> j
     """
 
     def cond(state):
-        active, _ = state
+        active, _, _ = state
         return jnp.any(active)
 
     def body(state):
-        active, seed = state
+        active, seed, rounds = state
         p_eff = jnp.where(active, priorities, _NEG)
         m2 = closed2_max(p_eff)
         newly = active & (p_eff == m2)
@@ -94,18 +98,19 @@ def luby_mis_rounds(priorities: jax.Array, active0: jax.Array, closed2_max) -> j
         b = jnp.where(newly, jnp.int32(1), jnp.int32(0))
         covered = closed2_max(b) > 0
         active = active & ~covered & ~newly
-        return active, seed
+        return active, seed, rounds + 1
 
     # derive from active0 (not a fresh constant) so the carry keeps the same
     # varying-manual-axes type under shard_map
     seed0 = active0 & False
-    _, seed = jax.lax.while_loop(cond, body, (active0, seed0))
-    return seed
+    _, seed, rounds = jax.lax.while_loop(cond, body,
+                                         (active0, seed0, jnp.int32(0)))
+    return seed, rounds
 
 
 def _luby_mis_sq(
     priorities: jax.Array, idx: jax.Array, idx_ok: jax.Array, active0: jax.Array
-) -> jax.Array:
+) -> Tuple[jax.Array, jax.Array]:
     """MIS of NG² on one device: local-adjacency ``closed2`` plug-in."""
     return luby_mis_rounds(
         priorities, active0, lambda p: _closed2_max(p, idx, idx_ok)
@@ -180,24 +185,38 @@ def _threshold_clustering(
     if t <= 1:  # degenerate: singletons
         labels = jnp.where(valid, jnp.cumsum(valid) - 1, -1).astype(jnp.int32)
         seed_of = jnp.where(valid, jnp.arange(n), -1).astype(jnp.int32)
-        return TCResult(labels, seed_of, valid, jnp.sum(valid).astype(jnp.int32))
+        return TCResult(labels, seed_of, valid, jnp.sum(valid).astype(jnp.int32),
+                        jnp.int32(0))
 
     k = t - 1
     # auto: avoid O(n²) HBM at scale (tuned winner when the policy is on;
     # trace-time read, pinned by the _dispatch static above)
     block = knn_block or resolve_auto_block(n, x.shape[1], k,
                                             dtype=str(x.dtype))
-    if n > block:
-        _, idx = knn_graph_blocked(x, k, valid=valid, block=block, impl=impl)
-    else:
-        _, idx = knn_graph(x, k, valid=valid, impl=impl)
-    idx = jnp.where(valid[:, None], idx, -1)           # invalid rows: no out-edges
-    idx_ok = idx >= 0                                   # kNN never returns invalid keys
+    # the scope names below are a contract with the benchmark (DESIGN.md §20)
+    with jax.named_scope("tc.knn"):
+        if n > block:
+            _, idx = knn_graph_blocked(x, k, valid=valid, block=block,
+                                       impl=impl)
+        else:
+            _, idx = knn_graph(x, k, valid=valid, impl=impl)
+        idx = jnp.where(valid[:, None], idx, -1)       # invalid rows: no out-edges
+        idx_ok = idx >= 0                               # kNN never returns invalid keys
 
-    priorities = seed_priorities(key, n)
+    with jax.named_scope("tc.mis"):
+        priorities = seed_priorities(key, n)
+        is_seed, mis_rounds = _luby_mis_sq(priorities, idx, idx_ok, valid)
 
-    is_seed = _luby_mis_sq(priorities, idx, idx_ok, valid)
+    with jax.named_scope("tc.grow"):
+        labels, seed_of, n_clusters = _grow(x, idx, idx_ok, is_seed, valid)
+    return TCResult(labels, seed_of, is_seed, n_clusters, mis_rounds)
 
+
+def _grow(x, idx, idx_ok, is_seed, valid):
+    """Steps 3–4 of TC: grow each seed over its graph neighbours, hand the
+    leftovers to the nearest seed, and compact cluster ids; returns
+    ``(labels, seed_of, n_clusters)``."""
+    n = x.shape[0]
     # ---- grow: each vertex adjacent to a seed joins that seed (unique by MIS) ----
     n_arange = jnp.arange(n, dtype=jnp.int32)
     safe = jnp.where(idx_ok, idx, 0)
@@ -248,5 +267,4 @@ def _threshold_clustering(
     seed_rank = (jnp.cumsum(is_seed.astype(jnp.int32)) - 1).astype(jnp.int32)
     labels = jnp.where(seed_of >= 0, seed_rank[jnp.where(seed_of >= 0, seed_of, 0)], -1)
     n_clusters = jnp.sum(is_seed).astype(jnp.int32)
-    return TCResult(labels.astype(jnp.int32), seed_of.astype(jnp.int32),
-                    is_seed, n_clusters)
+    return labels.astype(jnp.int32), seed_of.astype(jnp.int32), n_clusters
