@@ -122,7 +122,7 @@ def _kmeans(
         centers, _, _, it = state
         lab, _ = assign(centers)
         lab_safe = jnp.where(valid, lab, k)  # dropped by segment_sum
-        sums, mass = ops.blocked_segment_sum(
+        sums, mass, _ = ops.blocked_segment_sum(
             x, lab_safe, k, weights=w, n_blocks=n_blocks, impl=impl)
         new = jnp.where(
             (mass > 0)[:, None], sums / jnp.maximum(mass, 1e-30)[:, None], centers

@@ -263,8 +263,8 @@ def _folded_segment_sum(x_local, ids_local, n_out, weights_local, *,
             x_local[sl], ids_local[sl], n_out,
             weights=None if weights_local is None else weights_local[sl],
             impl=impl))
-    sums = jnp.stack([s for s, _ in parts])          # (sub, n_out, d)
-    masses = jnp.stack([m for _, m in parts])        # (sub, n_out)
+    sums = jnp.stack([s for s, _, _ in parts])       # (sub, n_out, d)
+    masses = jnp.stack([m for _, m, _ in parts])     # (sub, n_out)
     sums = _gather1d(sums, axis_name)                # (n_blocks, n_out, d)
     masses = _gather1d(masses, axis_name)
     acc_s, acc_m = sums[0], masses[0]

@@ -42,7 +42,8 @@ def _execute_memory(plan: FitPlan, x: jax.Array) -> Reduction:
     return Reduction(
         protos=r.protos, mass=r.mass, valid=r.valid,
         n_prototypes=r.n_prototypes, assignments=r.assignments,
-        n0=x.shape[0], counters={"mis_rounds": tuple(r.mis_rounds)},
+        n0=x.shape[0], counters={"mis_rounds": tuple(r.mis_rounds),
+                                 "reduce_tiles": tuple(r.reduce_tiles)},
     )
 
 

@@ -86,6 +86,7 @@ class ITISLevelOut(NamedTuple):
     assignment: jax.Array  # (n_in,) int32 → [0, n_out_max), -1 for padding
     n_clusters: jax.Array  # () int32
     mis_rounds: Optional[jax.Array] = None  # () int32 Luby rounds of the level
+    reduce_tiles: Optional[jax.Array] = None  # () int32 reduce tile matmuls
 
 
 class ITISResult(NamedTuple):
@@ -95,6 +96,7 @@ class ITISResult(NamedTuple):
     assignments: Sequence[jax.Array]  # one per level, for back-out
     n_prototypes: jax.Array           # () int32 — valid count at final level
     mis_rounds: Sequence[jax.Array] = ()  # () int32 per level run
+    reduce_tiles: Sequence[jax.Array] = ()  # () int32 per level run
 
 
 def itis_step(
@@ -157,7 +159,7 @@ def _itis_step(
             n_blocks=n_blocks,
         )
     return ITISLevelOut(ps.x, ps.mass, ps.valid, tc.labels, tc.n_clusters,
-                        tc.mis_rounds)
+                        tc.mis_rounds, ps.tiles)
 
 
 def itis(
@@ -202,7 +204,7 @@ def itis(
         mass = jnp.pad(mass, (0, pad))
         valid = jnp.pad(valid, (0, pad))
 
-    assignments, mis_rounds = [], []
+    assignments, mis_rounds, reduce_tiles = [], [], []
     cur_x, cur_m, cur_v = x, mass, valid
     n_protos = jnp.sum(cur_v).astype(jnp.int32)
     for level in range(m):
@@ -221,6 +223,8 @@ def itis(
             )
         assignments.append(out.assignment)
         mis_rounds.append(out.mis_rounds)
+        reduce_tiles.append(out.reduce_tiles)
         cur_x, cur_m, cur_v = out.protos, out.mass, out.valid
         n_protos = out.n_clusters
-    return ITISResult(cur_x, cur_m, cur_v, assignments, n_protos, mis_rounds)
+    return ITISResult(cur_x, cur_m, cur_v, assignments, n_protos, mis_rounds,
+                      reduce_tiles)

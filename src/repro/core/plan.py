@@ -242,8 +242,11 @@ class FitResult:
     ``counters``: device int32 counts the fit's programs returned, read
     without a host sync (DESIGN.md §20): ``mis_rounds`` (memory executor,
     a tuple of () scalars, the Luby rounds of each level run;
-    ``np.asarray`` stacks them) and ``lloyd_iters`` (k-means backend, a
-    () scalar). Other executors and backends leave theirs out.
+    ``np.asarray`` stacks them), ``reduce_tiles`` (memory executor, the
+    same shape: each level's prototype reduce, its (segment tile, row
+    tile) matmuls summed over the blocks, 0 on the reference path) and
+    ``lloyd_iters`` (k-means backend, a () scalar). Other executors and
+    backends leave theirs out.
 
     The old ``IHTCResult`` / ``StreamingIHTCResult`` names are deprecation
     aliases of this class.

@@ -24,6 +24,7 @@ class PrototypeSet(NamedTuple):
     x: jax.Array        # (n_max, d) prototype coordinates (padded)
     mass: jax.Array     # (n_max,) total original-unit mass per prototype
     valid: jax.Array    # (n_max,) bool — real prototype vs padding
+    tiles: Optional[jax.Array] = None  # () int32 segment-sum tile matmuls run
 
 
 def reduce_to_prototypes(
@@ -76,21 +77,22 @@ def _reduce_to_prototypes(
     safe_labels = jnp.where(labels >= 0, labels, n_max).astype(jnp.int32)
 
     if weighted:
-        sums, denom = ops.blocked_segment_sum(
+        sums, denom, tiles = ops.blocked_segment_sum(
             x, safe_labels, n_max, weights=w, n_blocks=n_blocks, impl=impl)
         mass = denom
     else:
         ones = jnp.where(labels >= 0, 1.0, 0.0).astype(jnp.float32)
-        sums, denom = ops.blocked_segment_sum(
+        sums, denom, tiles = ops.blocked_segment_sum(
             x, safe_labels, n_max, weights=ones, n_blocks=n_blocks, impl=impl)
-        _, mass = ops.blocked_segment_sum(
+        _, mass, mass_tiles = ops.blocked_segment_sum(
             jnp.zeros((n, 1), x.dtype), safe_labels, n_max, weights=w,
             n_blocks=n_blocks, impl=impl,
         )
+        tiles = tiles + mass_tiles
     protos = sums / jnp.maximum(denom, 1e-12)[:, None]
     valid = denom > 0
     protos = jnp.where(valid[:, None], protos, 0.0).astype(x.dtype)
-    return PrototypeSet(protos, mass, valid)
+    return PrototypeSet(protos, mass, valid, tiles)
 
 
 def compose_assignments(levels: Sequence[jax.Array], final: jax.Array) -> jax.Array:

@@ -214,7 +214,13 @@ def segment_sum(
     *,
     weights: Optional[jax.Array] = None,
     impl: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Weighted segment sum; ids outside [0, num_segments) are dropped.
+
+    Returns ``(sums, masses, tiles)``: ``tiles`` is the () int32 count of
+    (segment tile, row tile) matmuls the Pallas kernel ran, 0 on the
+    reference path.
+    """
     tp = _tuned("segment_sum", x.dtype,
                 n=x.shape[0], d=x.shape[1], s=num_segments)
     if _resolve(impl, tuned=tp.get("impl")) == "pallas":
@@ -223,7 +229,8 @@ def segment_sum(
             x, segment_ids, num_segments, weights, interpret=_interpret(),
             **kw
         )
-    return ref.segment_sum(x, segment_ids, num_segments, weights=weights)
+    return (*ref.segment_sum(x, segment_ids, num_segments, weights=weights),
+            jnp.int32(0))
 
 
 def blocked_segment_sum(
@@ -234,7 +241,7 @@ def blocked_segment_sum(
     weights: Optional[jax.Array] = None,
     n_blocks: Optional[int] = None,
     impl: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Segment sum with a *fixed* reduction tree (DESIGN.md §4.3).
 
     Rows are split into ``n_blocks`` equal blocks (right-padded with dropped
@@ -248,6 +255,7 @@ def blocked_segment_sum(
 
     ``n_blocks`` defaults to the active runtime config's reduction width;
     ``n_blocks <= 1`` falls back to the plain one-shot ``segment_sum``.
+    Returns ``(sums, masses, tiles)``, ``tiles`` summed over the blocks.
     """
     if n_blocks is None:
         n_blocks = runtime.active().n_blocks
@@ -261,16 +269,17 @@ def blocked_segment_sum(
     ip = jnp.pad(segment_ids, (0, pad), constant_values=num_segments)
     wp = None if weights is None else jnp.pad(weights, (0, pad))
     nb = (n + pad) // n_blocks
-    sums = masses = None
+    sums = masses = tiles = None
     for b in range(n_blocks):  # static unroll: left fold in block order
         sl = slice(b * nb, (b + 1) * nb)
-        s_b, m_b = segment_sum(
+        s_b, m_b, t_b = segment_sum(
             xp[sl], ip[sl], num_segments,
             weights=None if wp is None else wp[sl], impl=impl,
         )
         sums = s_b if sums is None else sums + s_b
         masses = m_b if masses is None else masses + m_b
-    return sums, masses
+        tiles = t_b if tiles is None else tiles + t_b
+    return sums, masses, tiles
 
 
 def flash_attention(

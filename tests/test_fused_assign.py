@@ -174,7 +174,7 @@ def test_non_fused_ops_degrade_fused_impl(rng):
     x = jnp.asarray(rng.normal(size=(12, 3)), jnp.float32)
     with runtime.configure(impl="fused"):
         d = ops.pairwise_sq_l2(x, x)
-        s, m = ops.segment_sum(x, jnp.zeros((12,), jnp.int32), 2)
+        s, m, _ = ops.segment_sum(x, jnp.zeros((12,), jnp.int32), 2)
     np.testing.assert_allclose(np.asarray(d),
                                np.asarray(ops.pairwise_sq_l2(x, x)),
                                rtol=0, atol=0)

@@ -23,7 +23,7 @@ from repro.kernels.fused_assign import (
 )
 from repro.kernels.knn_topk import knn_topk
 from repro.kernels.pairwise_l2 import pairwise_sq_l2
-from repro.kernels.segment_sum import segment_sum
+from repro.kernels.segment_sum import segment_sum, tile_shape
 
 # the random sweep needs hypothesis (requirements-dev.txt; CI installs
 # it); the pinned edge cases at the bottom run either way
@@ -131,11 +131,78 @@ def test_segment_sum_parity(case):
     # ids straddle the legal range: -1 and s are out of range -> dropped
     ids = jnp.asarray(rng.integers(-1, s + 1, size=n), jnp.int32)
     w = jnp.asarray(rng.random(n), jnp.float32) if weighted else None
-    gs, gm = segment_sum(x, ids, s, w, block_s=bs, block_n=bn,
-                         interpret=True)
+    gs, gm, _ = segment_sum(x, ids, s, w, block_s=bs, block_n=bn,
+                            interpret=True)
     ws, wm = ref.segment_sum(x, ids, s, weights=w)
     np.testing.assert_allclose(gs, ws, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(gm, wm, rtol=1e-4, atol=1e-4)
+
+
+#: how the banded sweep draws its ids
+ID_LAYOUTS = ("random", "dropped", "one_segment", "reversed", "empty_tiles")
+
+
+def _banded_ids(rng, layout, n, s):
+    if layout == "random":  # -1 and s are out of range -> dropped
+        return rng.integers(-1, s + 1, size=n)
+    if layout == "dropped":
+        return rng.choice([-1, s], size=n)
+    if layout == "one_segment":
+        return np.full(n, rng.integers(0, s))
+    if layout == "reversed":  # segment ids falling down the rows
+        return np.sort(rng.integers(0, s, size=n))[::-1]
+    # members only in the first and last segment tiles: whole tiles empty
+    return rng.choice([0, 1, s - 2, s - 1], size=n)
+
+
+@st.composite
+def banded_cases(draw):
+    n = draw(st.sampled_from(NS))
+    d = draw(st.sampled_from(DS))
+    s = draw(st.sampled_from((17, 40, 100)))
+    layout = draw(st.sampled_from(ID_LAYOUTS))
+    seed = draw(st.integers(0, 2**16))
+    weighted = draw(st.booleans())
+    bs = draw(st.sampled_from(TILES))
+    bn = draw(st.sampled_from(TILES))
+    return n, d, s, layout, bs, bn, seed, weighted
+
+
+@SWEEP
+@given(case=banded_cases())
+def test_segment_sum_banded_parity(case):
+    """Segment counts spanning several segment tiles take the sorted,
+    banded schedule; it stays within S_tiles + J_tiles - 1 visits."""
+    n, d, s, layout, bs, bn, seed, weighted = case
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    ids = jnp.asarray(_banded_ids(rng, layout, n, s), jnp.int32)
+    w = jnp.asarray(rng.random(n), jnp.float32) if weighted else None
+    gs, gm, tiles = segment_sum(x, ids, s, w, block_s=bs, block_n=bn,
+                                interpret=True)
+    ws, wm = ref.segment_sum(x, ids, s, weights=w)
+    np.testing.assert_allclose(gs, ws, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(gm, wm, rtol=1e-4, atol=1e-4)
+    bs_, bn_, S, R = tile_shape(n, s, bs, bn, interpret=True)
+    assert 0 <= int(tiles) <= S // bs_ + R // bn_ - 1
+    if layout == "dropped":
+        assert int(tiles) == (R // bn_ if S == bs_ else 0)
+
+
+def test_segment_sum_banded_matches_dense(rng):
+    """The same sums through the dense grid (one segment tile) and the
+    banded schedule (seven), to float32 summation order."""
+    n, d, s = 300, 5, 100
+    x = jnp.asarray(rng.random((n, d)) + 0.5, jnp.float32)  # no cancellation
+    ids = jnp.asarray(rng.integers(-1, s + 1, size=n), jnp.int32)
+    w = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
+    ds, dm, dt = segment_sum(x, ids, s, w, block_s=128, block_n=32,
+                             interpret=True)
+    bs, bm, bt = segment_sum(x, ids, s, w, block_s=16, block_n=32,
+                             interpret=True)
+    np.testing.assert_allclose(bs, ds, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(bm, dm, rtol=1e-6, atol=0)
+    assert int(dt) == 10 and 7 <= int(bt) <= 7 + 10 - 1
 
 
 def dyadic(rng, shape, scale=0.25, lim=16):

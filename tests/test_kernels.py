@@ -98,7 +98,7 @@ def test_segment_sum(rng, n, d, s):
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     ids = jnp.asarray(rng.integers(-1, s + 1, size=n), jnp.int32)  # incl. OOB
     w = jnp.asarray(rng.random(n), jnp.float32)
-    gs, gm = ops.segment_sum(x, ids, s, weights=w, impl="pallas")
+    gs, gm, _ = ops.segment_sum(x, ids, s, weights=w, impl="pallas")
     ws, wm = ref.segment_sum(x, ids, s, weights=w)
     np.testing.assert_allclose(gs, ws, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(gm, wm, rtol=1e-4, atol=1e-4)
@@ -112,31 +112,36 @@ def test_blocked_segment_sum_matches_plain(rng, n, d, s, n_blocks):
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     ids = jnp.asarray(rng.integers(-1, s + 1, size=n), jnp.int32)  # incl. OOB
     w = jnp.asarray(rng.random(n), jnp.float32)
-    gs, gm = ops.blocked_segment_sum(x, ids, s, weights=w, n_blocks=n_blocks,
-                                     impl="ref")
+    gs, gm, _ = ops.blocked_segment_sum(x, ids, s, weights=w,
+                                        n_blocks=n_blocks, impl="ref")
     ws, wm = ref.segment_sum(x, ids, s, weights=w)
     np.testing.assert_allclose(gs, ws, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(gm, wm, rtol=1e-5, atol=1e-5)
 
 
-def test_blocked_segment_sum_shard_fold_identity(rng):
+# the Pallas case spans three 512-segment tiles: the banded schedule
+@pytest.mark.parametrize("impl,s", [("ref", 7), ("pallas", 1500)])
+def test_blocked_segment_sum_shard_fold_identity(rng, impl, s):
     """Bitwise contract used by the distributed pipeline (DESIGN.md §4.3):
     per-block partials folded left in block order == blocked_segment_sum."""
-    n, d, s, B = 64, 3, 7, 8
+    n, d, B = 64, 3, 8
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, s, size=n), jnp.int32)
     w = jnp.asarray(rng.random(n), jnp.float32)
-    gs, gm = ops.blocked_segment_sum(x, ids, s, weights=w, n_blocks=B,
-                                     impl="ref")
+    gs, gm, gt = ops.blocked_segment_sum(x, ids, s, weights=w, n_blocks=B,
+                                         impl=impl)
     nb = n // B
-    acc_s = acc_m = None
+    acc_s = acc_m = acc_t = None
     for b in range(B):  # what each shard computes, folded in shard order
         sl = slice(b * nb, (b + 1) * nb)
-        ps, pm = ops.segment_sum(x[sl], ids[sl], s, weights=w[sl], impl="ref")
+        ps, pm, pt = ops.segment_sum(x[sl], ids[sl], s, weights=w[sl],
+                                     impl=impl)
         acc_s = ps if acc_s is None else acc_s + ps
         acc_m = pm if acc_m is None else acc_m + pm
+        acc_t = pt if acc_t is None else acc_t + pt
     np.testing.assert_array_equal(np.asarray(gs), np.asarray(acc_s))
     np.testing.assert_array_equal(np.asarray(gm), np.asarray(acc_m))
+    assert int(gt) == int(acc_t)
 
 
 @pytest.mark.parametrize("lq,lk", [(8, 8), (1, 33), (17, 64), (64, 17)])

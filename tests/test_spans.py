@@ -13,6 +13,7 @@ from repro.cluster.kmeans import _kmeans, kmeans, kmeans_masked
 from repro.core.itis import _itis_step
 from repro.core.plan import plan_fit
 from repro.core.tc import _luby_mis_sq, seed_priorities, threshold_clustering
+from repro.kernels.segment_sum import tile_schedule, tile_shape
 
 
 def _points(n=256, d=4, seed=0):
@@ -125,9 +126,44 @@ def test_lloyd_iters_equal_kmeans_iters():
     assert int(masked.iters) == int(kmeans(x, 5, key=key).iters)
 
 
+def _schedule_tiles(labels, n_out, n_blocks=8):
+    """The reduce's tile matmuls at one level, from the schedule helper:
+    each block of the fixed fold, for the sums and the masses."""
+    ids = np.where(labels >= 0, labels, n_out).astype(np.int32)
+    ids = np.pad(ids, (0, (-len(ids)) % n_blocks), constant_values=n_out)
+    total = 0
+    for block in np.split(ids, n_blocks):
+        bs, bn, S, R = tile_shape(len(block), n_out, 512, 1024,
+                                  interpret=True)
+        if S == bs:  # one segment tile: the dense grid
+            tiles = R // bn
+        else:
+            keys = np.sort(np.where(block < n_out, block, S))
+            keys = np.pad(keys, (0, R - len(block)), constant_values=S)
+            tiles = int(tile_schedule(jnp.asarray(keys), S // bs, bs, bn)[3])
+        assert tiles <= S // bs + R // bn - 1
+        total += 2 * tiles  # unweighted: the sums' call and the masses'
+    return total
+
+
+def test_fit_counts_reduce_tiles_from_the_schedule():
+    x = _points(2048)
+    res = repro.fit(x, 2, 2, "kmeans", k=4, key=jax.random.PRNGKey(2),
+                    impl="pallas")
+    got = np.asarray(res.counters["reduce_tiles"])
+    sizes = [a.shape[0] for a in res.assignments] + [res.protos.shape[0]]
+    want = [_schedule_tiles(np.asarray(a), n_out)
+            for a, n_out in zip(res.assignments, sizes[1:])]
+    assert got.dtype == np.int32 and got.tolist() == want
+    assert min(want) > 0
+    # the reference path runs no tiles
+    ref = repro.fit(x, 2, 2, "kmeans", k=4, key=jax.random.PRNGKey(2))
+    assert np.asarray(ref.counters["reduce_tiles"]).tolist() == [0, 0]
+
+
 def test_other_backends_leave_lloyd_iters_out():
     res = repro.fit(_points(300), 2, 2, "hac", k=3)
-    assert set(res.counters) == {"mis_rounds"}
+    assert set(res.counters) == {"mis_rounds", "reduce_tiles"}
 
 
 def test_tracing_perturbs_no_result(tmp_path):

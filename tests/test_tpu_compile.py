@@ -65,6 +65,16 @@ CASES = {
         lambda x, i, w: segment_sum(x, i, 32768, w),
         [((65536, 128), jnp.float32), ((65536,), jnp.int32),
          ((65536,), jnp.float32)]),
+    # the banded reduce at the ITIS level-0 blocks of the benchmark's cells:
+    # 10^6 x 128 (sift1m) and 581,012 x 6 (covertype) in 8 blocks
+    "segment_sum_banded_125000x128_S500000": (
+        lambda x, i, w: segment_sum(x, i, 500000, w),
+        [((125000, 128), jnp.float32), ((125000,), jnp.int32),
+         ((125000,), jnp.float32)]),
+    "segment_sum_banded_72627x6_S290506": (
+        lambda x, i, w: segment_sum(x, i, 290506, w),
+        [((72627, 6), jnp.float32), ((72627,), jnp.int32),
+         ((72627,), jnp.float32)]),
 }
 
 
