@@ -6,7 +6,11 @@ The runner is chosen by the traffic file's ``loop``; there is one:
           executor. Set-up draws the data and runs one full fit, which
           compiles (or loads) every program a fit uses. The window ends
           when the fit that crosses ``seconds`` completes; ``fit_s`` is the
-          window over the fits completed in it. ``--trace 1`` traces one fit.
+          window over the fits completed in it. ``--trace 1`` traces one fit
+          and records its level and k-means program calls; after the
+          window, each call's compiled text gives the trace its scope
+          paths (:mod:`chipbench.scopes`), and the run fails where the
+          calls recorded are not the programs' runs in the trace.
 
 Every number compared by ``correct`` comes from :mod:`chipbench.checks`,
 after the window has closed and the peak memory has been read.
@@ -22,7 +26,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
-from chipbench import checks, data, reference, work
+from chipbench import checks, data, reference, scopes, work
 from chipbench import trace as trace_mod
 from chipbench.catalog import BENCH_DIR, Cell
 
@@ -76,7 +80,12 @@ class ReadCtx:
     d: int = 0
     knn_k: int = 0
     level_valid: List[int] = dataclasses.field(default_factory=list)
+    #: the backend's k, and the valid prototypes it clusters
+    k: int = 0
+    proto_valid: int = 0
     fits: int = 0
+    #: the traced fit's ``FitResult.counters``, on the host
+    counters: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
 def _now() -> float:
@@ -148,20 +157,27 @@ def run_fit(cell: Cell, seed: int, seconds: float, tracing: bool,
     _log(f"setup: seconds={setup_s} compile={counters.total}")
 
     path = _trace_dir(cell, seed)
+    calls: list = []
+    recording = (scopes.recording(calls) if tracing
+                 else contextlib.nullcontext())
     counters.in_window = True
-    fits, t0 = 0, _now()
-    with _traced(tracing, path):
+    fits, t0, ends = 0, _now(), []
+    with recording, _traced(tracing, path):
         while True:
             with _span(tracing, "bench.fit"):
                 res = fit(jax.random.fold_in(kfit, fits))
             fits += 1
-            if tracing or _now() - t0 >= seconds:
+            ends.append(_now())
+            if tracing or ends[-1] - t0 >= seconds:
                 break
     window_s = _now() - t0
     counters.in_window = False
     peak = _peak_bytes(jax.devices()[0])
-    _log(f"window: fits={fits} seconds={window_s} "
+    each = np.diff([t0] + ends).tolist()
+    _log(f"window: fits={fits} seconds={window_s} each={each} "
          f"compiles_in_window={counters.window}")
+    texts = scopes.compiled_texts(calls)  # no calls unless tracing
+    calls.clear()
 
     limits = checks.limits_for(cfg, "fit")
     numbers = checks.fit_numbers(x, res, fc, data.host_rng(seed, 1),
@@ -171,9 +187,13 @@ def run_fit(cell: Cell, seed: int, seconds: float, tracing: bool,
            "fit_bss_tss": reference.bss_tss(x, res.labels, int(fc["k"]))}
     ctx = ReadCtx(loop="fit", d=int(cfg["d"]), knn_k=int(fc["t"]) - 1,
                   level_valid=[int((np.asarray(a) >= 0).sum())
-                               for a in res.assignments], fits=fits)
+                               for a in res.assignments], k=int(fc["k"]),
+                  proto_valid=int(np.asarray(res.proto_valid).sum()),
+                  fits=fits)
     if tracing:
-        ctx.trace = trace_mod.load(path, remove=True)
+        ctx.counters = jax.device_get(getattr(res, "counters", {}))
+        ctx.trace = scopes.load(path, remove=True)
+        scopes.attach_recorded(ctx.trace, texts)
     return Outcome(ok, fits, 0, e2e, chk, ctx,
                    {"memory_peak_bytes": peak, "window_s": window_s})
 

@@ -8,7 +8,10 @@ algorithm's definition in straightforward NumPy (float64, on the host) or
 The kNN graph of every row comes from :func:`knn_all`, float32 distances
 at ``Precision.HIGHEST`` in the expansion form; where a decision is close,
 :func:`kth_nn_dist` and :func:`pair_dist` settle it in the difference form
-and in float64.
+and in float64. :func:`kmeans_moves` does the same on the host, float64
+distances in the expansion form a block of rows at a time and the close
+rows again in the difference form, so that it holds O(block·k) for any
+number of prototypes p and centres k.
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ import numpy as np
 #: a distance decision closer than this share of the squared norms involved
 #: is a tie to float32 rounding (2^-20: sixteen float32 epsilons)
 ROUNDING = 2.0 ** -20
+#: prototype rows that :func:`kmeans_moves` holds against every centre
+MOVES_BLOCK = 4096
 
 
 # ---------------------------------------------------------------------------
@@ -77,7 +82,17 @@ def kmeans_moves(protos, mass, valid, labels, k: int) -> int:
     members, every prototype's label is its nearest centre. The number of
     valid prototypes whose label's centre lies farther than the nearest
     centre by more than float32 rounding (:data:`ROUNDING` of the squared
-    norms of the prototype and both centres). float64."""
+    norms of the prototype and both centres), the distances taken in the
+    difference form ``Σ (p - c)²``. float64.
+
+    The host holds O(:data:`MOVES_BLOCK`·k), not O(p·k·d): distances are
+    first taken that many rows at a time in the expansion form
+    ``|p|² + |c|² - 2 p·c``, and a row is decided there only where neither
+    its margin against the slack nor its nearest centre against the second
+    nearest lies within twice :func:`_expansion_error` of it. Every other
+    row is settled again in the difference form
+    (:func:`_moves_by_difference`), so the count is the difference form's,
+    row for row."""
     v = np.asarray(valid, bool) & (np.asarray(labels) >= 0)
     p = np.asarray(protos, np.float64)[v]
     lab = np.asarray(labels)[v]
@@ -86,13 +101,57 @@ def kmeans_moves(protos, mass, valid, labels, k: int) -> int:
     centres, den = segment_mean(p, lab, k, weights=np.asarray(mass)[v])
     present = den > 0
     cp = centres[present]
-    d = ((p[:, None, :] - cp[None, :, :]) ** 2).sum(-1)
-    near = d.argmin(axis=1)
     col = (np.cumsum(present) - 1)[lab]
-    rows = np.arange(p.shape[0])
     pn, cn = (p * p).sum(-1), (cp * cp).sum(-1)
-    slack = ROUNDING * (2 * pn + cn[col] + cn[near])
-    return int((d[rows, col] - d[rows, near] > slack).sum())
+    err = _expansion_error(p.shape[1], pn, cn)
+    block, moves = MOVES_BLOCK, 0
+    for lo in range(0, p.shape[0], block):
+        s = slice(lo, lo + block)
+        rows = np.arange(p[s].shape[0])
+        d = p[s] @ cp.T
+        d *= -2
+        d += pn[s, None]
+        d += cn[None, :]
+        near = d.argmin(axis=1)
+        nearest = d[rows, near]
+        margin = d[rows, col[s]] - nearest
+        slack = ROUNDING * (2 * pn[s] + cn[col[s]] + cn[near])
+        d[rows, near] = np.inf
+        # NaN compares false: a row with a non-finite distance is close
+        decided = ((d.min(axis=1) - nearest > 2 * err[s])
+                   & (np.abs(margin - slack) > 2 * err[s]))
+        del d
+        moves += int((decided & (margin > slack)).sum())
+        close = lo + np.flatnonzero(~decided)
+        moves += _moves_by_difference(p[close], cp, col[close], pn[close],
+                                      cn, max(1, block // p.shape[1]))
+    return moves
+
+
+def _expansion_error(dim: int, pn, cn):
+    """Per row, a bound on how far a float64 distance to any centre in the
+    expansion form may lie from the same distance in the difference form:
+    each is within ~(2·dim + 5) units of rounding of ``|p|² + |c|²``
+    (dot products and sums of ``dim`` terms), and this allows four times
+    their sum."""
+    return 8 * (dim + 4) * np.finfo(np.float64).eps * (pn + cn.max())
+
+
+def _moves_by_difference(p, cp, col, pn, cn, rows: int) -> int:
+    """:func:`kmeans_moves`'s count over the rows ``p`` with their label
+    columns ``col``, every distance in the difference form, ``rows`` rows
+    against every centre at a time."""
+    moves = 0
+    for lo in range(0, p.shape[0], rows):
+        s = slice(lo, lo + rows)
+        d = p[s, None, :] - cp[None, :, :]
+        np.square(d, out=d)
+        d = d.sum(-1)
+        near = d.argmin(axis=1)
+        r = np.arange(d.shape[0])
+        slack = ROUNDING * (2 * pn[s] + cn[col[s]] + cn[near])
+        moves += int((d[r, col[s]] - d[r, near] > slack).sum())
+    return moves
 
 
 def bss_tss(x, labels, k: int) -> float:

@@ -1,6 +1,6 @@
 """The program's own spans in a profiler trace: device operations grouped by
 their ``jax.named_scope`` path, the program's ``repro.*`` host spans, and
-device time per ITIS level.
+device time per ITIS level; and the fit's own counters.
 
 ``load`` reads a trace with :func:`chipbench.trace.load` and adds the
 program's host spans (``repro.*``), so the idle gaps fall under the
@@ -16,19 +16,28 @@ path comes from the optimized HLO text of each program that ran
 (:func:`op_names`), instruction by instruction, a fit's n-th run of a
 program taken as the n-th module of that name given
 (:meth:`ScopedTrace.attach_paths`). Paths are kept beside the trace's
-events, in ``ScopedTrace.paths``.
+events, in ``ScopedTrace.paths``. :func:`recording` records the calls of
+the level and k-means programs while a fit runs,
+:func:`compiled_texts` gives each call's optimized HLO text afterwards,
+and :func:`attach_recorded` attaches their paths once it has matched the
+calls recorded to the programs' runs in the trace.
 
-The names of the scopes and spans read here are the program's contract
-(DESIGN.md §20).
+The names of the scopes, spans and counters read here are the program's
+contract (DESIGN.md §20). The readers of ``metrics/`` take them per fit
+with :func:`scope_seconds_per_fit` and :func:`counter_total`.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import glob
+import importlib
 import os
 import re
 import shutil
 from typing import Dict, List, Optional
+
+import numpy as np
 
 from chipbench import trace as trace_mod
 from chipbench.trace import Event, Trace
@@ -40,6 +49,12 @@ FIT_SPAN = "bench.fit"
 #: device scopes of the fit path, in the order a level runs them
 ITIS_SCOPES = ("tc.knn", "tc.mis", "tc.grow", "itis.reduce")
 BACKEND_SCOPES = ("kmeans.seed",)
+BACKEND_PROGRAM = r"_kmeans"
+#: the programs whose calls :func:`recording` records, by module and name
+PROGRAMS = (("repro.core.itis", "_itis_step"),
+            ("repro.cluster.kmeans", "_kmeans"))
+#: the name a trace gives their runs (``jit__itis_step``)
+RECORDED_MODULES = tuple(f"jit_{attr}" for _, attr in PROGRAMS)
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTR = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?metadata=\{[^}]*'
@@ -200,3 +215,99 @@ def load(path: str, *, remove: bool = False) -> ScopedTrace:
     if remove:
         shutil.rmtree(path, ignore_errors=True)
     return ScopedTrace(tr.ops, tr.modules, host, tr.window, tr.chips)
+
+
+@contextlib.contextmanager
+def recording(calls: list):
+    """Record each call of the level and k-means programs in ``calls``
+    while the block runs: the jitted function and its arguments, each
+    array by its shape and dtype only, so that no buffer outlives the
+    call (an uncommitted array lowers to the same program)."""
+    import jax
+
+    def shape_of(a):
+        return (jax.ShapeDtypeStruct(a.shape, a.dtype)
+                if isinstance(a, jax.Array) else a)
+
+    saved = []
+    for mod_name, attr in PROGRAMS:
+        mod = importlib.import_module(mod_name)
+        fn = getattr(mod, attr)
+        saved.append((mod, attr, fn))
+
+        def call(*args, _fn=fn, **kwargs):
+            calls.append((_fn, jax.tree.map(shape_of, args),
+                          jax.tree.map(shape_of, kwargs)))
+            return _fn(*args, **kwargs)
+
+        setattr(mod, attr, call)
+    try:
+        yield
+    finally:
+        for mod, attr, fn in saved:
+            setattr(mod, attr, fn)
+
+
+def compiled_texts(calls: list) -> List[str]:
+    """The optimized HLO text of each recorded call's executable, as the
+    process compiled it or loaded it from the compile cache."""
+    return [fn.lower(*a, **kw).compile().as_text() for fn, a, kw in calls]
+
+
+def program_runs(tr: Trace) -> Dict[str, int]:
+    """Runs of each recorded program (:data:`RECORDED_MODULES`) inside the
+    fits' spans of ``tr``."""
+    out: Dict[str, int] = {}
+    for f in fits(tr):
+        for m in tr.modules:
+            if m.name in RECORDED_MODULES and f.start <= m.start < f.end:
+                out[m.name] = out.get(m.name, 0) + 1
+    return out
+
+
+def attach_recorded(tr: ScopedTrace, texts: List[str]) -> int:
+    """Give ``tr`` the scope paths of the recorded calls' compiled
+    ``texts`` (:func:`compiled_texts`); returns how many operations took a
+    path. Each recorded program must have run in the traced fits as many
+    times as :func:`recording` saw it called, and some operation must take
+    a path: otherwise the program no longer calls them where
+    :func:`recording` looks, every scope metric would read nothing, and
+    this raises. A trace with no device program (a CPU run) takes none."""
+    if not tr.modules:
+        return 0
+    names = by_module(texts)
+    recorded = {m: len(v) for m, v in names.items()}
+    ran = program_runs(tr)
+    if recorded != ran:
+        raise RuntimeError(f"recorded program calls {recorded} are not the "
+                           f"runs traced in the fit {ran}")
+    done = tr.attach_paths(names)
+    if done <= 0:
+        raise RuntimeError(f"no traced operation took a scope path from the "
+                           f"recorded programs {recorded}")
+    return done
+
+
+# ---------------------------------------------------------------------------
+# what the per-layer readers take
+
+
+def scope_seconds_per_fit(ctx, scope: str, program: str) -> Optional[float]:
+    """Device seconds per fit of the leaf operations inside ``scope`` in
+    the programs matching ``program``; None where the trace carries no
+    scope paths or the scope took no time."""
+    tr = ctx.trace
+    if ctx.loop != "fit" or not ctx.fits or not isinstance(tr, ScopedTrace):
+        return None
+    s = tr.scope_seconds(scope, module=program)
+    return s / ctx.fits if s > 0 else None
+
+
+def counter_total(ctx, name: str) -> Optional[float]:
+    """The last fit's counter ``name`` (``FitResult.counters``), summed
+    over the levels where it holds one a level; None where the fit has no
+    such counter or it counted nothing."""
+    if ctx.loop != "fit" or name not in ctx.counters:
+        return None
+    total = float(np.sum(ctx.counters[name]))
+    return total if total > 0 else None

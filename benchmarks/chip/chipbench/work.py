@@ -60,6 +60,16 @@ def knn_work(level_valid: Sequence[int], d: int, k: int) -> Work:
     return Work(flops, nbytes)
 
 
+def lloyd_work(p: int, k: int, d: int, iters: int) -> Work:
+    """The nearest-centre passes of a k-means fit after its seeding: one in
+    each of its ``iters`` Lloyd iterations and one for the final labels,
+    each every valid prototype against every centre, 2·p·k·d FLOP; the
+    p·d f32 prototypes and k·d centres read once and the p labels written
+    once a pass."""
+    passes = iters + 1
+    return Work(passes * 2.0 * p * k * d, passes * 4.0 * (p * d + k * d + p))
+
+
 def roofline_percent(work: Work, kernel_seconds: float, pk: Peaks):
     """Share of the roofline in percent; None where the kernel never ran
     or there are no peaks to hold it against."""

@@ -31,8 +31,6 @@ host spans but no device operations.
 from __future__ import annotations
 
 import argparse
-import contextlib
-import importlib
 import json
 import statistics
 import sys
@@ -46,39 +44,6 @@ sys.path.insert(0, str(HERE.parents[1] / "src"))
 REHEARSED = 3
 
 
-#: the programs whose scopes are read, by module and function name
-PROGRAMS = (("repro.core.itis", "_itis_step"),
-            ("repro.cluster.kmeans", "_kmeans"))
-
-
-@contextlib.contextmanager
-def recording(calls: list):
-    """Record each call of the level and k-means programs (the jitted
-    function and its arguments) while the block runs."""
-    saved = []
-    for mod_name, attr in PROGRAMS:
-        mod = importlib.import_module(mod_name)
-        fn = getattr(mod, attr)
-        saved.append((mod, attr, fn))
-
-        def call(*args, _fn=fn, **kwargs):
-            calls.append((_fn, args, kwargs))
-            return _fn(*args, **kwargs)
-
-        setattr(mod, attr, call)
-    try:
-        yield
-    finally:
-        for mod, attr, fn in saved:
-            setattr(mod, attr, fn)
-
-
-def compiled_texts(calls: list) -> list:
-    """The optimized HLO text of each recorded call's executable, as the
-    process compiled it or loaded it from the compile cache."""
-    return [fn.lower(*a, **kw).compile().as_text() for fn, a, kw in calls]
-
-
 def measure(cell, seed: int, untraced: int, path: str) -> dict:
     import jax
     import numpy as np
@@ -90,10 +55,10 @@ def measure(cell, seed: int, untraced: int, path: str) -> dict:
     x = data.draw(cfg, kdata)
     fit = harness._fit_fn(x, fc)
     calls: list = []
-    with recording(calls):
+    with scopes.recording(calls):
         fit(kwarm)
     t0 = time.perf_counter()
-    names = scopes.by_module(compiled_texts(calls))
+    names = scopes.by_module(scopes.compiled_texts(calls))
     names_s = time.perf_counter() - t0
     walls = []
     for i in range(untraced):

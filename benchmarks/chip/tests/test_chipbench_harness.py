@@ -101,6 +101,65 @@ def _rehearse(workload, seconds=1.0, **data_overrides):
     return out
 
 
+def test_untraced_run_records_no_program_call(monkeypatch):
+    """Scope paths and counters are for the traced run alone: an untraced
+    run records no call and fetches no counter."""
+    from chipbench import scopes
+
+    def refuse(*_):
+        raise AssertionError("scope work in an untraced run")
+
+    monkeypatch.setattr(scopes, "recording", refuse)
+    monkeypatch.setattr(scopes, "attach_recorded", refuse)
+    out = _rehearse("covertype.fit")
+    assert out.correct, out.checks
+    assert out.ctx.trace is None
+    assert out.ctx.counters == {}
+    assert out.ctx.k == 7
+    assert 0 < out.ctx.proto_valid <= N // 2 ** 4
+
+
+def test_traced_run_reads_scope_paths_and_counters(monkeypatch):
+    """The traced fit's level and k-means calls are recorded and their
+    compiled texts read after the window; the trace keeps the program's
+    host spans, and the counter metrics reach the result line. (A CPU
+    trace has no device operations, so no scope time is read here.)"""
+    from chipbench import scopes
+    from repro import runtime
+
+    texts = []
+    orig = scopes.compiled_texts
+
+    def spy(calls):
+        texts.extend(orig(calls))
+        return texts
+
+    monkeypatch.setattr(scopes, "compiled_texts", spy)
+    cell = harness.resized(Catalog().cell("covertype.fit"), N)
+    jax.clear_caches()
+    with runtime.configure(impl="pallas", interpret=True, tune="off"):
+        out = harness.run_cell(cell, SEED, 1.0, True, harness._now(),
+                               harness.Counters())
+    jax.clear_caches()
+    assert out.correct, out.checks
+    assert out.attempted == 1
+    modules = scopes.by_module(texts)
+    assert {k: len(v) for k, v in modules.items()} == {"jit__itis_step": 4,
+                                                       "jit__kmeans": 1}
+    assert isinstance(out.ctx.trace, scopes.ScopedTrace)
+    assert len(out.ctx.counters["mis_rounds"]) == 4
+    assert set(out.ctx.counters) == {"mis_rounds", "reduce_tiles",
+                                     "lloyd_iters"}
+    names = {e.name for e in out.ctx.trace.host}
+    assert {"bench.fit", "repro.fit", "repro.itis.level"} <= names
+    line = harness.result_line(cell, out, True, rehearse=True)
+    for name in ("mis_rounds.fit", "reduce_tiles.fit"):
+        assert line["metrics"][name]["unit"] == "count"
+        assert line["metrics"][name]["value"] >= 1
+    assert "lloyd_iters.fit" not in line["metrics"]  # sift1m's alone
+    assert "knn_device_s.fit" not in line["metrics"]
+
+
 def _failing(out):
     return sorted(k for k, c in out.checks.items() if c["value"] > c["limit"])
 

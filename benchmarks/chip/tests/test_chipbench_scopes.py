@@ -1,13 +1,14 @@
 """The program's spans in a trace: device time by scope path, device time
 per ITIS level matched to the level spans or to the fit's level count
 (the ``itis_level0_device_s.fit`` reader), idle gaps named by the
-innermost ``repro.*`` span, a CPU trace of a real fit, and the spans
-script's rehearsal."""
+innermost ``repro.*`` span, a CPU trace of a real fit, the spans
+script's rehearsal, and the per-layer readers of scopes and counters."""
 import json
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -250,3 +251,177 @@ def test_scope_paths_from_the_hlo_text():
     assert tr.scope_seconds("tc.knn") == pytest.approx(0.037)
     assert tr.scope_seconds("tc.mis") == pytest.approx(0.007)
     assert tr.scope_seconds("itis.reduce") == pytest.approx(0.015)
+
+
+# ---------------------------------------------------------------------------
+# the per-layer readers of scopes and counters
+
+SCOPE_METRICS = {  # metric: device seconds per fit in synthetic()'s 2 fits
+    "knn_device_s.fit": 0.026 / 2, "mis_device_s.fit": 0.005 / 2,
+    "grow_device_s.fit": 0.003 / 2, "reduce_device_s.fit": 0.020 / 2,
+    "kmeans_seed_device_s.fit": 0.002 / 2}
+COUNTER_METRICS = {"mis_rounds.fit": 9.0, "reduce_tiles.fit": 13.0,
+                   "lloyd_iters.fit": 3.0}
+COUNTERS = {"mis_rounds": (np.int32(5), np.int32(4)),
+            "reduce_tiles": (np.int32(10), np.int32(3)),
+            "lloyd_iters": np.int32(3)}
+
+
+@pytest.fixture(scope="module")
+def catalog():
+    return Catalog()
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_scope_reader_reads_device_seconds_per_fit(catalog, metric):
+    ctx = ReadCtx(loop="fit", trace=synthetic(), fits=2)
+    assert catalog.reader(metric)(ctx) == pytest.approx(SCOPE_METRICS[metric])
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_scope_reader_reads_nothing_without_its_scope(catalog, metric):
+    read = catalog.reader(metric)
+    tr = synthetic()
+    # the trace as the harness loads it carries no scope paths
+    assert read(ReadCtx(loop="fit", trace=_as_the_harness_loads_it(tr),
+                        fits=2)) is None
+    # a scoped trace whose operations carry no such scope
+    scope = {"knn_device_s.fit": "tc.knn", "mis_device_s.fit": "tc.mis",
+             "grow_device_s.fit": "tc.grow",
+             "reduce_device_s.fit": "itis.reduce",
+             "kmeans_seed_device_s.fit": "kmeans.seed"}[metric]
+    tr.paths = {e: p for e, p in tr.paths.items()
+                if not scopes.in_scope(p, scope)}
+    assert read(ReadCtx(loop="fit", trace=tr, fits=2)) is None
+    assert read(ReadCtx(loop="fit", trace=None, fits=2)) is None
+    assert read(ReadCtx(loop="fit", trace=synthetic(), fits=0)) is None
+
+
+@pytest.mark.parametrize("metric", sorted(COUNTER_METRICS))
+def test_counter_reader_sums_the_last_fits_levels(catalog, metric):
+    ctx = ReadCtx(loop="fit", counters=COUNTERS)
+    assert catalog.reader(metric)(ctx) == COUNTER_METRICS[metric]
+
+
+@pytest.mark.parametrize("metric", sorted(COUNTER_METRICS))
+def test_counter_reader_reads_nothing_without_a_count(catalog, metric):
+    read = catalog.reader(metric)
+    name = metric[:-len(".fit")]
+    assert read(ReadCtx(loop="fit")) is None
+    missing = {k: v for k, v in COUNTERS.items() if k != name}
+    assert read(ReadCtx(loop="fit", counters=missing)) is None
+    # off the Pallas path reduce_tiles is 0 at every level: nothing read
+    zero = dict(COUNTERS, **{name: (np.int32(0), np.int32(0))})
+    assert read(ReadCtx(loop="fit", counters=zero)) is None
+
+
+def test_the_four_itis_scopes_cover_what_they_hold(catalog):
+    ctx = ReadCtx(loop="fit", trace=synthetic(), fits=2)
+    four = sum(catalog.reader(m)(ctx) for m in (
+        "knn_device_s.fit", "mis_device_s.fit", "grow_device_s.fit",
+        "reduce_device_s.fit"))
+    itis = catalog.reader("itis_device_s.fit")(ctx)
+    # the unscoped copy and "tc.knn.extra", 16 ms over two fits, stay out
+    assert itis - four == pytest.approx(0.008)
+
+
+def test_earlier_readers_read_the_scoped_trace_as_the_plain_one(catalog):
+    """Loading the trace with the program's spans and scope paths leaves
+    every earlier per-layer metric as it was, to the bit."""
+    from chipbench import work
+
+    scoped_tr = synthetic()
+    plain = _as_the_harness_loads_it(scoped_tr)
+    assert any(e.name.startswith("repro.") for e in scoped_tr.host)
+    for name in ("device_idle_frac.fit", "itis_device_s.fit",
+                 "kmeans_device_s.fit", "knn_roofline.fit",
+                 "itis_level0_device_s.fit"):
+        read = catalog.reader(name)
+        got = [read(ReadCtx(loop="fit", trace=tr, peaks=work.peaks(
+            "TPU v5 lite"), d=128, knn_k=1, level_valid=[600, 200], fits=2))
+            for tr in (plain, scoped_tr)]
+        assert got[0] is not None and got[0] == got[1], name
+
+
+def test_scope_and_counter_metrics_are_registered_on_the_fit_cells(catalog):
+    """Every one on both fit cells but ``lloyd_iters.fit``, on sift1m alone:
+    covertype's k = 7 lands in one optimum or another by seed, and its
+    Lloyd count with it."""
+    want = set(SCOPE_METRICS) | set(COUNTER_METRICS)
+    for name in ("sift1m.fit", "covertype.fit"):
+        cell = catalog.cell(name)
+        here = want - ({"lloyd_iters.fit"} if name == "covertype.fit"
+                       else set())
+        assert here <= set(cell.readers)
+        assert ("lloyd_iters.fit" in cell.readers) == (name == "sift1m.fit")
+        for m in cell.per_layer:
+            if m["name"] in want:
+                assert m["moves"] == "fit_s"
+
+
+def test_a_lloyd_roofline_reads_from_the_context_alone():
+    """What a later reader of the Lloyd step's roofline needs is in the
+    context: the backend's k, its valid prototypes, the width, the traced
+    fit's Lloyd count, and the step's device time outside the seeding."""
+    from chipbench import work
+
+    def lloyd_roofline(ctx):
+        tr = ctx.trace
+        ns = sum(e.end - e.start for e in tr.ops
+                 if e.leaf and e.group == "jit__kmeans"
+                 and e.name.startswith("pairwise_sq_l2")
+                 and not scopes.in_scope(tr.paths.get(e, ""), "kmeans.seed"))
+        iters = scopes.counter_total(ctx, "lloyd_iters")
+        w = work.lloyd_work(ctx.proto_valid, ctx.k, ctx.d, int(iters))
+        return work.roofline_percent(w, ns / 1e9 / ctx.fits, ctx.peaks)
+
+    ctx = ReadCtx(loop="fit", trace=synthetic(), fits=2,
+                  peaks=work.peaks("TPU v5 lite"), d=128, k=256,
+                  proto_valid=873, counters={"lloyd_iters": np.int32(2)})
+    # three passes of 873 x 256 x 128, memory bound, in 8 ms over two fits
+    want = 3 * 4.0 * (873 * 128 + 256 * 128 + 873) / 819e9 / 0.004 * 100
+    assert lloyd_roofline(ctx) == pytest.approx(want)
+
+
+def _one_fit(modules):
+    host = [Event("bench.fit", 0, 100 * MS),
+            Event("repro.itis.level", 0, 1 * MS),
+            Event("repro.itis.level", 50 * MS, 51 * MS)]
+    ops = [_op("_fused_topk.6", 10, 20, "jit__itis_step", ""),
+           _op("segment_sum.16", 25, 40, "jit__itis_step", ""),
+           _op("_fused_topk.6", 63, 90, "jit__itis_step", "")]
+    return scoped(ops, [Event(n, s * MS, t * MS, "0") for n, s, t in modules],
+                  host)
+
+
+TWO_LEVELS = [("jit__itis_step", 10, 40), ("jit__itis_step", 60, 90)]
+
+
+@pytest.mark.parametrize("case", ["matched", "a_call_not_recorded",
+                                  "a_program_not_recorded", "no_path",
+                                  "no_device_program"])
+def test_attach_recorded_matches_the_calls_to_the_traced_runs(case):
+    """The paths are attached only where each recorded program ran in the
+    traced fit as often as it was called; a program the recording missed,
+    or texts that give no operation a path, fail the run."""
+    texts = [HLO, HLO]
+    modules = TWO_LEVELS
+    if case == "a_call_not_recorded":
+        texts = [HLO]
+    elif case == "a_program_not_recorded":
+        modules = TWO_LEVELS + [("jit__kmeans", 92, 95)]
+    elif case == "no_path":
+        texts = [HLO.replace("_fused_topk.6", "other.1")
+                 .replace("segment_sum.16", "other.2")] * 2
+    elif case == "no_device_program":
+        modules = []
+    tr = _one_fit(modules)
+    if case == "matched":
+        assert scopes.program_runs(tr) == {"jit__itis_step": 2}
+        assert scopes.attach_recorded(tr, texts) == 3
+    elif case == "no_device_program":
+        assert scopes.attach_recorded(tr, texts) == 0
+        assert not tr.paths
+    else:
+        with pytest.raises(RuntimeError):
+            scopes.attach_recorded(tr, texts)
